@@ -17,7 +17,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.fhe import ops
@@ -66,11 +65,11 @@ def parallel_shallow_mul(
     ctx = FheContext(params=params, keys=keys, policy=ExecPolicy(backend="ref"))
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P("aff"), P("aff"), P("aff"), P("aff"), P()),
         out_specs=(P("aff"), P("aff")),
-        check_rep=False,
+        check_vma=False,
     )
     def run(a0s, a1s, b0s, b1s, rlk_arr):
         outs0, outs1 = [], []
@@ -114,8 +113,8 @@ def lower_multi_job_step(params: CkksParams, keys: KeySet, mesh: Mesh, jobs_per_
                 outs1.append(out.c1)
             return jnp.stack(outs0), jnp.stack(outs1)
 
-        f = shard_map(body, mesh=mesh, in_specs=(P("aff"),) * 4,
-                      out_specs=(P("aff"), P("aff")), check_rep=False)
+        f = jax.shard_map(body, mesh=mesh, in_specs=(P("aff"),) * 4,
+                          out_specs=(P("aff"), P("aff")), check_vma=False)
         return f(a0, a1, b0, b1)
 
     return jax.jit(run).lower(spec, spec, spec, spec)

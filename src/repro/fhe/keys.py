@@ -5,6 +5,11 @@ q_0..q_L is partitioned into dnum digits of ≤ α consecutive primes.  The key 
 digit j encrypts  P·F_j·s'  under s over the extended basis Q∪P, where
 F_j = Q̂_j·[Q̂_j^{-1}]_{Q_j}  satisfies  F_j ≡ 1 (mod q∈D_j), ≡ 0 (mod q∉D_j).
 Level restriction is pure limb-dropping — the congruences hold per limb.
+
+Keygen is host precompute: every NTT and pointwise op here runs the uint64
+oracle (``backend="ref"``) explicitly, so keys are the same bits whichever
+device evaluates later, and keygen runs under ``jax.default_device(cpu)``
+on a machine whose default backend is a TPU.
 """
 
 from __future__ import annotations
@@ -72,7 +77,7 @@ def keygen(params: CkksParams, seed: int = 0, h: int | None = None) -> SecretKey
     all_primes = params.all_primes
     s_rns = poly.to_rns_signed(s, all_primes)
     idx = tuple(range(len(all_primes)))
-    s_eval = poly.to_eval(s_rns, params, idx)
+    s_eval = poly.to_eval(s_rns, params, idx, "ref")
     return SecretKey(s_coeff=s, s_eval=s_eval)
 
 
@@ -88,7 +93,7 @@ def pkgen(params: CkksParams, sk: SecretKey, seed: int = 1) -> PublicKey:
     idx = poly.q_idx(params, params.L)
     a = jnp.asarray(_uniform_rns(rng, qp, params.n))
     e_coeff = _err_scale(params) * poly.sample_gaussian(rng, params.n)
-    e = poly.to_eval(poly.to_rns_signed(e_coeff, qp), params, idx)
+    e = poly.to_eval(poly.to_rns_signed(e_coeff, qp), params, idx, "ref")
     s_q = sk.s_eval[: params.L + 1]
     from repro.kernels.modops import ops as mo
 
@@ -129,7 +134,7 @@ def kskgen(params: CkksParams, sk: SecretKey, s_prime_eval: jnp.ndarray, seed: i
 
         a = jnp.asarray(_uniform_rns(rng, all_primes, n))
         e_coeff = _err_scale(params) * poly.sample_gaussian(rng, n)
-        e = poly.to_eval(poly.to_rns_signed(e_coeff, all_primes), params, idx_full)
+        e = poly.to_eval(poly.to_rns_signed(e_coeff, all_primes), params, idx_full, "ref")
         # b = -a·s + e + PFj·s'  (eval domain, per limb)
         asq = mo.pointwise_mulmod(a, sk.s_eval, qs, backend="ref")
         pf = mo.pointwise_mulmod(

@@ -23,7 +23,7 @@ Two pipeline shapes execute the same math:
     HBM-equivalent buffers between launches.
 
 ``backend`` selects both the pipeline and the stage numerics:
-  "fused"/"kernel" → fused Pallas pipeline (interpret off-TPU);
+  "fused"/"kernel" → fused Pallas pipeline (interpreted off-TPU);
   "staged"         → staged pipeline, per-stage auto backends;
   "ref"            → staged pipeline, u64 oracle stages (jit-traceable);
   "auto"           → fused on TPU, staged-ref elsewhere (CPU tests stay fast).
@@ -37,10 +37,10 @@ from __future__ import annotations
 import dataclasses
 import functools
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import tpu
 from repro.kernels.bconv import ops as bconv_ops
 from repro.kernels.fusedks import ops as fused_ops
 from repro.kernels.hoistrot import ops as hoist_ops
@@ -63,9 +63,7 @@ def resolve_pipeline(backend: str) -> tuple[str, str]:
     if backend == "ref":
         return "staged", "ref"
     if backend == "auto":
-        if jax.default_backend() == "tpu":
-            return "fused", "auto"
-        return "staged", "ref"
+        return ("fused", "auto") if tpu.on_tpu() else ("staged", "ref")
     raise ValueError(f"unknown key-switch backend {backend!r}")
 
 

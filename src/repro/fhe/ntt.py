@@ -7,7 +7,7 @@ slot j of the result is the evaluation a(psi^(2j+1)) (natural order).
 Two executable forms share these plans:
   * ``repro.kernels.ntt.ref``    — uint64 iterative radix-2 oracle (fast on CPU/XLA);
   * ``repro.kernels.ntt.kernel`` — Pallas four-step kernel: an N1-point NTT is an
-    N1×N1 modular *matmul* on the MXU (8-bit limb decomposition, exact int32
+    N1×N1 modular *matmul* on the MXU (8-bit limbs as bf16 operands, exact f32
     accumulation, Montgomery recombination).  N = N1·N2 mirrors the paper's
     256×256 (bootstrappable, N=2^16) and 128×128 (swift, N=2^14) circuits.
 
@@ -20,6 +20,7 @@ import dataclasses
 import functools
 
 import numpy as np
+from ml_dtypes import bfloat16
 
 from . import modmath as mm
 
@@ -74,17 +75,20 @@ def _to_mont(v: np.ndarray, q: int) -> np.ndarray:
 
 
 def _limbs8(v: np.ndarray) -> np.ndarray:
-    """(..., ) u64 values < 2^31 → (NLIMB8, ...) int32 8-bit limbs."""
+    """(..., ) u64 values < 2^31 → (NLIMB8, ...) 8-bit limbs, exact in bf16 (MXU operands)."""
     v = v.astype(np.uint64)
     return np.stack(
-        [((v >> np.uint64(8 * k)) & np.uint64(0xFF)).astype(np.int32) for k in range(NLIMB8)],
+        [((v >> np.uint64(8 * k)) & np.uint64(0xFF)).astype(bfloat16) for k in range(NLIMB8)],
         axis=0,
     )
 
 
-@dataclasses.dataclass(frozen=True)
+@dataclasses.dataclass(frozen=True, eq=False)
 class NttPlan:
-    """All tables for one ring degree N over one RNS prime chain."""
+    """All tables for one ring degree N over one RNS prime chain.
+
+    Plans compare and hash by identity: they come from the cached
+    ``build_plan``/``subplan``, and their arrays make field equality moot."""
 
     n: int
     n1: int
@@ -98,8 +102,8 @@ class NttPlan:
     psi_pows: np.ndarray  # (L, N)  twist
     psiinv_ninv: np.ndarray  # (L, N)  psi^{-i}·N^{-1}
     # --- four-step kernel tables (plain-value limb matrices + mont twiddles) ---
-    v2_limbs: np.ndarray  # (L, NLIMB8, N2, N2) int32   row NTT matrix
-    v1_limbs: np.ndarray  # (L, NLIMB8, N1, N1) int32   col NTT matrix
+    v2_limbs: np.ndarray  # (L, NLIMB8, N2, N2) bf16   row NTT matrix
+    v1_limbs: np.ndarray  # (L, NLIMB8, N1, N1) bf16   col NTT matrix
     v2i_limbs: np.ndarray
     v1i_limbs: np.ndarray
     t_mont: np.ndarray  # (L, N1, N2) uint32  inter-step twiddle w^(n1·k2)·R
@@ -124,10 +128,10 @@ def build_plan(n: int, primes: tuple[int, ...]) -> NttPlan:
     winv_pows = np.zeros((L, n), np.uint64)
     psi_pows = np.zeros((L, n), np.uint64)
     psiinv_ninv = np.zeros((L, n), np.uint64)
-    v2_limbs = np.zeros((L, NLIMB8, n2, n2), np.int32)
-    v1_limbs = np.zeros((L, NLIMB8, n1, n1), np.int32)
-    v2i_limbs = np.zeros((L, NLIMB8, n2, n2), np.int32)
-    v1i_limbs = np.zeros((L, NLIMB8, n1, n1), np.int32)
+    v2_limbs = np.zeros((L, NLIMB8, n2, n2), bfloat16)
+    v1_limbs = np.zeros((L, NLIMB8, n1, n1), bfloat16)
+    v2i_limbs = np.zeros((L, NLIMB8, n2, n2), bfloat16)
+    v1i_limbs = np.zeros((L, NLIMB8, n1, n1), bfloat16)
     t_mont = np.zeros((L, n1, n2), np.uint32)
     ti_mont = np.zeros((L, n1, n2), np.uint32)
     twa_mont = np.zeros((L, n1, n2), np.uint32)

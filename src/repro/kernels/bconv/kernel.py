@@ -2,13 +2,18 @@
 
 The paper's BConv unit is l_sub = 60 parallel modular-multiply lanes feeding
 adder trees; on TPU the natural substrate is again the MXU.  out = Wᵀ·x̂ mod c
-is computed by 8-bit limb decomposition of both operands: partial products are
-≤ 255²·k < 2^22 for k ≤ 64 limbs, so int32 accumulation is exact; the seven
-limb diagonals are recombined with Montgomery constants 2^(8s)·R mod c_j.
+is computed by 8-bit limb decomposition of both operands, fed to the MXU as
+bf16 (exact for 0..255) with f32 accumulation: a limb product sums K8 terms
+≤ 255², exact below 2^24 for K8 ≤ 256.  Products are cast to int32, the ≤ 4
+of one limb diagonal summed (< 2^26), and the seven diagonals recombined with
+Montgomery constants 2^(8s)·R mod c_j (``ntt.kernel._mod_matmul``).
 
-Grid: (coefficient blocks,).  Per program: x̂ (K8, NB) + W (K8, M8) + out (M8, NB)
-⇒ ~(64·512 + 64·64 + 64·512)·4·(1+limb copies) ≈ 1.5 MB VMEM for NB=512.
-K8/M8 are the 8-padded limb counts (zero rows/cols are exact no-ops).
+Grid: (coefficient blocks,).  Per program: x̂ (K8, NB) uint32, the limbs of
+Wᵀ (NLIMB8, M8, K8) bf16 precomputed by the wrapper, and per-row constants as
+(M8, 1) columns.  K8/M8 are the 8-padded limb counts (zero rows/cols are
+exact no-ops).  Scoped VMEM the TPU compiler reports for a v5e at NB = 4096:
+1.94 MiB at dblookup (K8=8, M8=16), 2.69 MiB at lstm (K8=8, M8=24) — inside
+the 16 MiB default, so no ``vmem_limit_bytes`` is set.
 """
 
 from __future__ import annotations
@@ -20,56 +25,37 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.fhe.ntt import NDIAG, NLIMB8
-from repro.kernels.ntt.kernel import _montmul
+from repro.kernels import tpu
+from repro.kernels.ntt.kernel import _limbs, _mod_matmul
 
 
-def _bconv_kernel_body(x_ref, w_ref, c_ref, q_ref, qinv_ref, o_ref):
-    x = x_ref[...]  # (K8, NB) uint32
-    w = w_ref[...]  # (K8, M8) uint32
-    q = q_ref[...]  # (M8, 1)
-    qinv = qinv_ref[...]  # (M8, 1)
-    cm = c_ref[...]  # (M8, NDIAG)
-
-    x_limbs = [((x >> (8 * k)) & 0xFF).astype(jnp.int32) for k in range(NLIMB8)]
-    w_limbs = [((w >> (8 * k)) & 0xFF).astype(jnp.int32) for k in range(NLIMB8)]
-    diags = [None] * NDIAG
-    for kw in range(NLIMB8):
-        for kx in range(NLIMB8):
-            # (M8, K8) @ (K8, NB) → (M8, NB), exact in int32
-            p = jax.lax.dot_general(
-                w_limbs[kw].T,
-                x_limbs[kx],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32,
-            )
-            s = kw + kx
-            diags[s] = p if diags[s] is None else diags[s] + p
-    acc = jnp.zeros(diags[0].shape, jnp.uint32)
-    for s in range(NDIAG):
-        term = _montmul(diags[s].astype(jnp.uint32), cm[:, s : s + 1], q, qinv)
-        acc = acc + term
-        acc = jnp.where(acc >= q, acc - q, acc)
-    o_ref[...] = acc
+def _bconv_kernel_body(x_ref, wl_ref, c_ref, q_ref, qinv_ref, o_ref):
+    wl = [wl_ref[k] for k in range(NLIMB8)]  # (M8, K8) bf16 limbs of Wᵀ
+    cm = [c_ref[s] for s in range(NDIAG)]  # (M8, 1) each
+    # (M8, K8) @ (K8, NB) → (M8, NB) mod c_j
+    o_ref[...] = _mod_matmul(wl, _limbs(x_ref[...]), cm, q_ref[...], qinv_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def bconv_pallas(xhat, w, c_mont, q, qinv, *, interpret):
-    """xhat: (K8, N) u32; w: (K8, M8) u32; c_mont: (M8, NDIAG); q/qinv: (M8, 1)."""
+def bconv_pallas(xhat, wl, c_mont, q, qinv, *, interpret):
+    """xhat: (K8, N) u32; wl: (NLIMB8, M8, K8) bf16 limbs of Wᵀ;
+    c_mont: (NDIAG, M8, 1); q/qinv: (M8, 1)."""
     k8, n = xhat.shape
-    m8 = w.shape[1]
+    m8 = wl.shape[1]
     nb = min(n, 4096)
     assert n % nb == 0
-    return pl.pallas_call(
+    return tpu.call(
         _bconv_kernel_body,
+        (xhat, wl, c_mont, q, qinv),
         grid=(n // nb,),
         in_specs=[
             pl.BlockSpec((k8, nb), lambda i: (0, i)),
-            pl.BlockSpec((k8, m8), lambda i: (0, 0)),
-            pl.BlockSpec((m8, NDIAG), lambda i: (0, 0)),
+            pl.BlockSpec((NLIMB8, m8, k8), lambda i: (0, 0, 0)),
+            pl.BlockSpec((NDIAG, m8, 1), lambda i: (0, 0, 0)),
             pl.BlockSpec((m8, 1), lambda i: (0, 0)),
             pl.BlockSpec((m8, 1), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((m8, nb), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((m8, n), jnp.uint32),
         interpret=interpret,
-    )(xhat, w, c_mont, q, qinv)
+    )

@@ -11,20 +11,39 @@ never leave VMEM:
   accumulators stays resident in VMEM while all β digits stream through it.
   One program:
 
-    1. prescale   x̂_i = x_i ∘ [B̂_i⁻¹]_{b_i}        (one Montgomery mul/limb)
-    2. BConv row  y_e = Σ_i x̂_i · (B̂_i mod c_e)     (8-bit limb MXU dot)
+    1. prescale   x̂_i = x_i ∘ [B̂_i⁻¹]_{b_i}        (one Montgomery mul/row)
+    2. BConv row  y_e = Σ_i x̂_i · (B̂_i mod c_e)     (one Montgomery mul/row)
     3. NTT        ŷ_e = NTT_{c_e}(y_e)               (four-step MXU matmuls)
     4. KSK MAC    acc_{0,1}[e] += ŷ_e ∘ ksk_{j,{0,1}}[e]   (both components)
 
-Digits are padded to a uniform k8 source-limb count (zero rows with a dummy
-modulus are exact no-ops through every stage), so all β digits and both key
-components ride one grid.  A second entry point runs the same pipeline with a
-ModDown epilogue — (q_part − ŷ) ∘ P⁻¹ — for both accumulators at once.
+Stages 1–2 run on the VPU: one output row of a BConv is a k-term dot product,
+too thin for the MXU.  The weights arrive in Montgomery form, so each term is
+one Montgomery multiply of x̂_i (< b_i < 2^31) by [B̂_i·R]_{c_e}.  Digits are
+zero-padded to a uniform row count k = α (padded rows carry a dummy modulus and
+zero weights, exact no-ops), so all β digits and both key components ride one
+grid.  A second entry point runs the same pipeline with a ModDown epilogue —
+(q_part − ŷ) ∘ P⁻¹ — for a batch of accumulators.
 
-VMEM per program is dominated by the digit block (k8·N·4 B) plus the two NTT
-limb matrices (~2 MB at N=2^16); deep dnum=1 chains exceed VMEM on real TPUs
-and are served by the staged path — the dispatcher in ``ops`` stays honest
-about that limit.
+Layout: digit rows arrive in natural coefficient order as (N2, N1) tiles (the
+NTT transposes in VMEM), key/accumulator limbs as (N1, N2) slot tiles.  All
+scalars (per-limb moduli and Montgomery constants, per-row prescale constants,
+BConv weights) are flat uint32 SMEM tables.
+
+VMEM: a program holds the (k, N2, N1) digit block, the bf16 limb matrices
+of one limb's NTT, two twiddle tiles and the key/accumulator tiles, each
+double-buffered, and the body's scratch on top.  The TPU compiler's scoped
+VMEM figures for ``fused_ks_pallas`` on a v5e are blocks + scratch: 1.62 +
+0.23 MiB at dblookup (N=2^14, k=3); 8.50 + 1.79 MiB at lstm (N=2^16, k=7);
+13.50 + 1.79 = 15.29 MiB at logreg (k=17); 16.29 MiB at k=19, which it refuses
+against its 16 MiB default scoped limit.  The ModDown and hoisted-ModUp
+entries hold 1.0 / 1.5 MiB fewer block bytes at N=2^16 and the same scratch.
+``fused_vmem_bytes`` is the block sum (exact at N=2^14 and 2^16) plus eight
+uint32 tiles (2 MiB at N=2^16) for the scratch, so at N=2^16 it admits
+exactly the digit sizes the compiler fits, k ≤ 18 (``test_tpu_compile``
+holds it to that).  ``fusedks.ops`` applies it when it builds a shape's
+tables: a bound over ``tpu.VMEM_SCOPED_LIMIT`` (dnum=1 at L=57: 36 MiB)
+raises an error naming ``backend="staged"``; nothing is decided by catching
+a compile failure, and no kernel raises ``vmem_limit_bytes``.
 """
 
 from __future__ import annotations
@@ -35,179 +54,142 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.fhe.ntt import NDIAG, NLIMB8
-from repro.kernels.ntt.kernel import _mod_matmul_left, _montmul
+from repro.fhe.ntt import NLIMB8
+from repro.kernels import tpu
+from repro.kernels.ntt.kernel import _addmod, _montmul, limb_scalars, ntt_fwd_tile
+
+# per (digit, row) prescale constants in the flat SMEM table
+DB, DBINV, DBH = 0, 1, 2  # b, -b⁻¹ mod 2³², [B̂⁻¹]·R mod b
+NDSC = 3
 
 
-def _prescale_bconv_row(x, bh, b, binv, wcol, cm, q, qinv):
-    """Stages 1+2: one BConv output row, straight out of the prescale.
-
-    x: (k8, N) digit source limbs; bh: (k8, 1) [B̂⁻¹]·R mod b (Montgomery);
-    b/binv: (k8, 1) source moduli + their -b⁻¹ mod 2³²; wcol: (1, k8) B̂ mod c_e;
-    cm: (NDIAG,) Montgomery 2^(8s) mod c_e.  Returns (1, N) uint32 < c_e.
-    """
-    xhat = _montmul(x, bh, b, binv)  # x·B̂⁻¹ mod b, still (k8, N)
-    w_limbs = [((wcol >> (8 * k)) & 0xFF).astype(jnp.int32) for k in range(NLIMB8)]
-    x_limbs = [((xhat >> (8 * k)) & 0xFF).astype(jnp.int32) for k in range(NLIMB8)]
-    diags = [None] * NDIAG
-    for kw in range(NLIMB8):
-        for kx in range(NLIMB8):
-            p = jax.lax.dot_general(
-                w_limbs[kw],
-                x_limbs[kx],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32,
-            )  # (1, N), exact: 255²·k8 < 2^22
-            s = kw + kx
-            diags[s] = p if diags[s] is None else diags[s] + p
-    acc = jnp.zeros(diags[0].shape, jnp.uint32)
-    for s in range(NDIAG):
-        term = _montmul(diags[s].astype(jnp.uint32), cm[s], q, qinv)
-        acc = acc + term
-        acc = jnp.where(acc >= q, acc - q, acc)
-    return acc
+def fused_vmem_bytes(k: int, n1: int, n2: int) -> int:
+    """Scoped VMEM bound of one fused program (digit rows k): its
+    double-buffered blocks plus eight uint32 tiles for the body's scratch."""
+    n = n1 * n2
+    digit = k * n * 4
+    ntt = NLIMB8 * (n1 * n1 + n2 * n2) * 2 + 2 * n * 4  # bf16 V1/V2 + two twiddles
+    key_acc = 2 * (2 * n * 4)  # two key tiles in, two accumulator tiles out
+    scratch = 8 * n * 4
+    return 2 * (digit + ntt + key_acc) + scratch
 
 
-def _ntt_fwd_inline(y, twa, v2, v1, tm, cm, q, qinv, n1, n2):
-    """Stage 3: forward four-step negacyclic NTT of one limb, all in VMEM.
+def _bconv_row(x_ref, dsc_ref, wm_ref, j, e, q, qinv):
+    """Stages 1+2 for digit j → ext limb e: Σ_i x̂_i·B̂_i mod c_e, on the VPU."""
+    k = x_ref.shape[0]
+    m = wm_ref.shape[0] // (dsc_ref.shape[0] // NDSC)  # weights per source row
+    y = None
+    for i in range(k):
+        row = j * k + i
+        b = dsc_ref[row * NDSC + DB]
+        xh = _montmul(x_ref[i], dsc_ref[row * NDSC + DBH], b, dsc_ref[row * NDSC + DBINV])
+        t = _montmul(xh, wm_ref[row * m + e], q, qinv)
+        y = t if y is None else _addmod(y, t, q)
+    return y
 
-    Mirrors ``repro.kernels.ntt.kernel._ntt_kernel_body`` (inverse=False).
-    """
-    a = y.reshape(n2, n1).T
-    a = _montmul(a, twa, q, qinv)  # psi twist (A-layout)
-    b = _mod_matmul_left(v2, a.T, cm, q, qinv).T  # row NTTs
-    b = _montmul(b, tm, q, qinv)  # inter-step twiddle
-    c = _mod_matmul_left(v1, b, cm, q, qinv)  # col NTTs
-    return c.reshape(n1 * n2)
+
+def _modup_limb(sc_ref, dsc_ref, wm_ref, x_ref, twa_ref, v2_ref, v1_ref, t_ref, j, e):
+    """Stages 1–3: digit j's rows → ŷ_e, the NTT of its BConv onto limb e."""
+    q, qinv, r2, cm = limb_scalars(sc_ref, e)
+    y = _bconv_row(x_ref, dsc_ref, wm_ref, j, e, q, qinv)
+    v2 = [v2_ref[s] for s in range(NLIMB8)]
+    v1 = [v1_ref[s] for s in range(NLIMB8)]
+    return ntt_fwd_tile(y, twa_ref[...], v2, v1, t_ref[...], cm, q, qinv), q, qinv, r2
 
 
-def _fused_ks_body(
-    xd_ref, bh_ref, b_ref, binv_ref, w_ref, twa_ref, v2_ref, v1_ref, t_ref,
-    c_ref, q_ref, qinv_ref, r2_ref, ksk_ref, o_ref, *, n1, n2,
-):
-    j = pl.program_id(1)  # digit index — innermost, accumulates into o_ref
-    q = q_ref[0, 0]
-    qinv = qinv_ref[0, 0]
-    r2 = r2_ref[0, 0]
-    cm = c_ref[0]  # (NDIAG,)
-
-    y = _prescale_bconv_row(
-        xd_ref[0], bh_ref[0], b_ref[0], binv_ref[0], w_ref[0].T, cm, q, qinv
+def _fused_ks_body(sc_ref, dsc_ref, wm_ref, xd_ref, twa_ref, v2_ref, v1_ref, t_ref,
+                   ksk_ref, o_ref):
+    e, j = pl.program_id(0), pl.program_id(1)  # j innermost: accumulates into o_ref
+    yhat, q, qinv, r2 = _modup_limb(
+        sc_ref, dsc_ref, wm_ref, xd_ref, twa_ref, v2_ref, v1_ref, t_ref, j, e
     )
-    yhat = _ntt_fwd_inline(
-        y.reshape(-1), twa_ref[0], v2_ref[0], v1_ref[0], t_ref[0], cm, q, qinv, n1, n2
-    )
-
     # stage 4: plain products ŷ∘ksk via Montgomery double-multiply, accumulate
-    k0 = ksk_ref[0, 0, 0]
-    k1 = ksk_ref[0, 1, 0]
-    t0 = _montmul(_montmul(yhat, k0, q, qinv), r2, q, qinv)
-    t1 = _montmul(_montmul(yhat, k1, q, qinv), r2, q, qinv)
+    t0 = _montmul(_montmul(yhat, ksk_ref[0], q, qinv), r2, q, qinv)
+    t1 = _montmul(_montmul(yhat, ksk_ref[1], q, qinv), r2, q, qinv)
 
     @pl.when(j == 0)
     def _():
-        o_ref[0, 0] = t0
-        o_ref[0, 1] = t1
+        o_ref[0] = t0
+        o_ref[1] = t1
 
     @pl.when(j > 0)
     def _():
-        s0 = o_ref[0, 0] + t0
-        o_ref[0, 0] = jnp.where(s0 >= q, s0 - q, s0)
-        s1 = o_ref[0, 1] + t1
-        o_ref[0, 1] = jnp.where(s1 >= q, s1 - q, s1)
+        o_ref[0] = _addmod(o_ref[0], t0, q)
+        o_ref[1] = _addmod(o_ref[1], t1, q)
 
 
-@functools.partial(jax.jit, static_argnames=("n1", "n2", "interpret"))
-def fused_ks_pallas(xd, bh, b, binv, w, twa, v2, v1, t, cm, q, qinv, r2, ksk, *, n1, n2, interpret):
+def _ntt_specs(n1, n2, limb):
+    """BlockSpecs of one limb's forward-NTT tables; ``limb`` maps grid → limb."""
+    return [
+        pl.BlockSpec((None, n1, n2), lambda *g: (limb(*g), 0, 0)),  # twist
+        pl.BlockSpec((None, NLIMB8, n2, n2), lambda *g: (limb(*g), 0, 0, 0)),  # V2
+        pl.BlockSpec((None, NLIMB8, n1, n1), lambda *g: (limb(*g), 0, 0, 0)),  # V1
+        pl.BlockSpec((None, n1, n2), lambda *g: (limb(*g), 0, 0)),  # inter-step twiddle
+    ]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def fused_ks_pallas(xd, sc, dsc, wm, twa, v2, v1, t, ksk, *, interpret):
     """All β digits × both key components of one key-switch in one launch.
 
-    xd:  (β, k8, N) digit source limbs (coeff domain, rows zero-padded)
-    bh/b/binv: (β, k8, 1) per-digit prescale constants
-    w:   (β, k8, m) BConv weights B̂_i mod c_e
-    twa/v2/v1/t/cm/q/qinv/r2: ext-basis NTT plan tables, leading (m, ...) axis
-    ksk: (β, 2, m, N) switching-key limbs (eval domain)
-    Returns (m, 2, N): the two MAC accumulators over the extended basis.
+    xd:  (β, k, N2, N1) digit source limbs (coeff domain, rows zero-padded)
+    sc:  (m·NSC,) ext-basis limb scalars; dsc: (β·k·NDSC,) prescale constants;
+    wm:  (β·k·m,) Montgomery BConv weights [B̂_i·R]_{c_e}
+    twa/v2/v1/t: ext-basis forward NTT tables, leading (m, ...) axis
+    ksk: (β, 2, m, N1, N2) switching-key limbs (eval domain)
+    Returns (m, 2, N1, N2): the two MAC accumulators over the extended basis.
     """
-    beta, k8, n = xd.shape
-    m = w.shape[2]
-    return pl.pallas_call(
-        functools.partial(_fused_ks_body, n1=n1, n2=n2),
+    beta, k, n2, n1 = xd.shape
+    m = twa.shape[0]
+    return tpu.call(
+        _fused_ks_body,
+        (sc, dsc, wm, xd, twa, v2, v1, t, ksk),
         grid=(m, beta),
-        in_specs=[
-            pl.BlockSpec((1, k8, n), lambda e, j: (j, 0, 0)),  # xd
-            pl.BlockSpec((1, k8, 1), lambda e, j: (j, 0, 0)),  # bh
-            pl.BlockSpec((1, k8, 1), lambda e, j: (j, 0, 0)),  # b
-            pl.BlockSpec((1, k8, 1), lambda e, j: (j, 0, 0)),  # binv
-            pl.BlockSpec((1, k8, 1), lambda e, j: (j, 0, e)),  # w column e
-            pl.BlockSpec((1, n1, n2), lambda e, j: (e, 0, 0)),  # twist
-            pl.BlockSpec((1, NLIMB8, n2, n2), lambda e, j: (e, 0, 0, 0)),  # V2
-            pl.BlockSpec((1, NLIMB8, n1, n1), lambda e, j: (e, 0, 0, 0)),  # V1
-            pl.BlockSpec((1, n1, n2), lambda e, j: (e, 0, 0)),  # inter-step twiddle
-            pl.BlockSpec((1, NDIAG), lambda e, j: (e, 0)),  # diagonal mont consts
-            pl.BlockSpec((1, 1), lambda e, j: (e, 0)),  # q
-            pl.BlockSpec((1, 1), lambda e, j: (e, 0)),  # qinv_neg
-            pl.BlockSpec((1, 1), lambda e, j: (e, 0)),  # r2
-            pl.BlockSpec((1, 2, 1, n), lambda e, j: (j, 0, e, 0)),  # ksk
-        ],
-        out_specs=pl.BlockSpec((1, 2, n), lambda e, j: (e, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, 2, n), jnp.uint32),
+        in_specs=[tpu.smem(), tpu.smem(), tpu.smem(),
+                  pl.BlockSpec((None, k, n2, n1), lambda e, j: (j, 0, 0, 0))]
+        + _ntt_specs(n1, n2, lambda e, j: e)
+        + [pl.BlockSpec((None, 2, None, n1, n2), lambda e, j: (j, 0, e, 0, 0))],
+        out_specs=pl.BlockSpec((None, 2, n1, n2), lambda e, j: (e, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((m, 2, n1, n2), jnp.uint32),
         interpret=interpret,
-    )(xd, bh, b, binv, w, twa, v2, v1, t, cm, q, qinv, r2, ksk)
-
-
-def _fused_moddown_body(
-    pc_ref, bh_ref, b_ref, binv_ref, w_ref, twa_ref, v2_ref, v1_ref, t_ref,
-    c_ref, q_ref, qinv_ref, qpart_ref, pinv_ref, o_ref, *, n1, n2,
-):
-    q = q_ref[0, 0]
-    qinv = qinv_ref[0, 0]
-    cm = c_ref[0]
-    y = _prescale_bconv_row(
-        pc_ref[0], bh_ref[...], b_ref[...], binv_ref[...], w_ref[...].T, cm, q, qinv
     )
-    yhat = _ntt_fwd_inline(
-        y.reshape(-1), twa_ref[0], v2_ref[0], v1_ref[0], t_ref[0], cm, q, qinv, n1, n2
+
+
+def _fused_moddown_body(sc_ref, dsc_ref, wm_ref, pinv_ref, pc_ref, twa_ref, v2_ref,
+                        v1_ref, t_ref, qp_ref, o_ref):
+    e = pl.program_id(1)
+    yhat, q, qinv, _ = _modup_limb(
+        sc_ref, dsc_ref, wm_ref, pc_ref, twa_ref, v2_ref, v1_ref, t_ref, 0, e
     )
     # ModDown epilogue: (q_part − BConv_P→Q(⌊·⌉)) ∘ P⁻¹, still in VMEM
-    d = qpart_ref[0, 0]
+    d = qp_ref[...]
     diff = jnp.where(d >= yhat, d - yhat, d + q - yhat)
-    o_ref[0, 0] = _montmul(diff, pinv_ref[0, 0], q, qinv)
+    o_ref[...] = _montmul(diff, pinv_ref[e], q, qinv)
 
 
-@functools.partial(jax.jit, static_argnames=("n1", "n2", "interpret"))
-def fused_moddown_pallas(pc, bh, b, binv, w, twa, v2, v1, t, cm, q, qinv, qpart, pinv, *, n1, n2, interpret):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def fused_moddown_pallas(pc, sc, dsc, wm, pinv, twa, v2, v1, t, qpart, *, interpret):
     """Fused prescale→BConv→NTT→(sub, ×P⁻¹) for a batch of accumulators.
 
-    pc:    (C, k8, N) P-block coefficients of the accumulators after the iNTT
-           (C = 2 for one key-switch's pair; C = 2·R when a hoisted rotation
-           group ModDowns every rotation's pair in one launch)
-    bh/b/binv: (k8, 1) prescale constants for the special block
-    w:     (k8, m) B̂ mod q_e;  qpart: (C, m, N) eval-domain q limbs
-    pinv:  (m, 1) Montgomery [P⁻¹]_{q_e}
-    NTT tables carry the q-basis (m = level+1 limbs).  Returns (C, m, N).
+    pc:    (C, k, N2, N1) P-block coefficients of the accumulators after the
+           iNTT (C = 2 for one key-switch's pair; C = 2·R when a hoisted
+           rotation group ModDowns every rotation's pair in one launch)
+    sc:    (m·NSC,) q-basis limb scalars; dsc/wm: prescale constants and
+           Montgomery BConv weights of the special block (one digit)
+    pinv:  (m,) Montgomery [P⁻¹]_{q_e};  qpart: (C, m, N1, N2) eval q limbs
+    NTT tables carry the q-basis (m = level+1 limbs).  Returns (C, m, N1, N2).
     """
-    nb, k8, n = pc.shape
-    m = w.shape[1]
-    return pl.pallas_call(
-        functools.partial(_fused_moddown_body, n1=n1, n2=n2),
+    nb, k, n2, n1 = pc.shape
+    m = twa.shape[0]
+    return tpu.call(
+        _fused_moddown_body,
+        (sc, dsc, wm, pinv, pc, twa, v2, v1, t, qpart),
         grid=(nb, m),
-        in_specs=[
-            pl.BlockSpec((1, k8, n), lambda c, e: (c, 0, 0)),  # pc
-            pl.BlockSpec((k8, 1), lambda c, e: (0, 0)),  # bh
-            pl.BlockSpec((k8, 1), lambda c, e: (0, 0)),  # b
-            pl.BlockSpec((k8, 1), lambda c, e: (0, 0)),  # binv
-            pl.BlockSpec((k8, 1), lambda c, e: (0, e)),  # w column e
-            pl.BlockSpec((1, n1, n2), lambda c, e: (e, 0, 0)),  # twist
-            pl.BlockSpec((1, NLIMB8, n2, n2), lambda c, e: (e, 0, 0, 0)),  # V2
-            pl.BlockSpec((1, NLIMB8, n1, n1), lambda c, e: (e, 0, 0, 0)),  # V1
-            pl.BlockSpec((1, n1, n2), lambda c, e: (e, 0, 0)),  # inter-step twiddle
-            pl.BlockSpec((1, NDIAG), lambda c, e: (e, 0)),  # diagonal mont consts
-            pl.BlockSpec((1, 1), lambda c, e: (e, 0)),  # q
-            pl.BlockSpec((1, 1), lambda c, e: (e, 0)),  # qinv_neg
-            pl.BlockSpec((1, 1, n), lambda c, e: (c, e, 0)),  # qpart
-            pl.BlockSpec((1, 1), lambda c, e: (e, 0)),  # pinv (mont)
-        ],
-        out_specs=pl.BlockSpec((1, 1, n), lambda c, e: (c, e, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, m, n), jnp.uint32),
+        in_specs=[tpu.smem()] * 4
+        + [pl.BlockSpec((None, k, n2, n1), lambda c, e: (c, 0, 0, 0))]
+        + _ntt_specs(n1, n2, lambda c, e: e)
+        + [pl.BlockSpec((None, None, n1, n2), lambda c, e: (c, e, 0, 0))],
+        out_specs=pl.BlockSpec((None, None, n1, n2), lambda c, e: (c, e, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb, m, n1, n2), jnp.uint32),
         interpret=interpret,
-    )(pc, bh, b, binv, w, twa, v2, v1, t, cm, q, qinv, qpart, pinv)
+    )

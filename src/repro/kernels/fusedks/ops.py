@@ -6,13 +6,16 @@ hybrid key-switch (everything between the shared iNTT and ModDown);
 ModDown for both accumulators.  Backends:
 
   * "kernel" — the fused Pallas pipeline, ONE launch per region
-    (interpret=True off-TPU, so CPU tests exercise the same program);
+    (interpreted off-TPU, so CPU tests exercise the same program);
   * "ref"    — the staged oracle in ``ref`` (one launch per stage per digit);
   * "auto"   — kernel on TPU, ref elsewhere (repo-wide convention).
 
-Tables are cached per (params, level): digit spans, per-digit prescale
-constants in Montgomery form, BConv weight matrices, and the extended-basis
-NTT plan views — all the state the fused kernel streams per grid step.
+Tables are cached per (params, level): digit spans, per-row prescale
+constants and Montgomery BConv weights (flat SMEM tables), and the
+extended-basis NTT tables — all the state the fused kernel streams per grid
+step.  Building them first applies the one shape rule (``_check_fits``): a
+digit size whose VMEM bound (``kernel.fused_vmem_bytes``) exceeds
+``tpu.VMEM_SCOPED_LIMIT`` raises and names ``backend="staged"``.
 """
 
 from __future__ import annotations
@@ -20,154 +23,115 @@ from __future__ import annotations
 import dataclasses
 import functools
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.fhe import modmath as mm
+from repro.fhe import ntt as nttmod
 from repro.fhe import poly, rns
 from repro.fhe.params import CkksParams
-from repro.kernels import dispatch
+from repro.kernels import dispatch, tpu
+from repro.kernels.ntt import ops as ntt_ops
 
 from . import kernel as _k
 from . import ref as _ref
 
 
-def _pad8(v: int) -> int:
-    return (v + 7) // 8 * 8
-
 _PAD_MOD = 3  # dummy odd modulus for zero-padded source rows (exact no-op)
 
 
-def _resolve(backend: str) -> str:
-    if backend == "auto":
-        return "kernel" if jax.default_backend() == "tpu" else "ref"
-    return backend
+def _check_fits(k: int, n: int) -> None:
+    """The stated rule for fused shapes: refuse, loudly, a program over budget."""
+    need = _k.fused_vmem_bytes(k, *nttmod.fourstep_split(n))
+    if need > tpu.VMEM_SCOPED_LIMIT:
+        raise ValueError(
+            f"fused key-switch at N={n} with {k}-row digits needs {need} B of "
+            f"scoped VMEM, over the {tpu.VMEM_SCOPED_LIMIT} B limit; "
+            "run this shape with backend='staged'"
+        )
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(frozen=True)
 class KsTables:
     """Per-(params, level) constants for the fused key-switch kernel."""
 
     beta: int
-    k8: int
+    k: int  # rows per digit block (α; short digits are zero-padded)
     m: int
-    n1: int
-    n2: int
     spans: tuple[tuple[int, int], ...]  # (lo, hi) master-chain slice per digit
-    bh: jnp.ndarray  # (β, k8, 1) [B̂⁻¹]·R mod b
-    b: jnp.ndarray  # (β, k8, 1) source moduli
-    binv: jnp.ndarray  # (β, k8, 1) -b⁻¹ mod 2³²
-    w: jnp.ndarray  # (β, k8, m) B̂ mod c_e
-    twa: jnp.ndarray
-    v2: jnp.ndarray
-    v1: jnp.ndarray
-    t: jnp.ndarray
-    cm: jnp.ndarray
-    q: jnp.ndarray
-    qinv: jnp.ndarray
-    r2: jnp.ndarray
+    dsc: jnp.ndarray  # (β·k·NDSC,) prescale constants, layout ``kernel.DB..DBH``
+    wm: jnp.ndarray  # (β·k·m,) Montgomery BConv weights [B̂_i·R]_{c_e}
+    ntt: ntt_ops.KernelTables  # forward NTT over the destination basis
 
 
-def _prescale_tables(digits: list[tuple[int, ...]], dst_primes, k8: int):
-    """(bh, b, binv, w) padded to (len(digits), k8, ·) for the given digit list."""
+def _prescale_tables(digits: list[tuple[int, ...]], dst_primes, k: int):
+    """Flat (dsc, wm) SMEM tables for the digit list, rows zero-padded to k."""
     nd = len(digits)
-    m = len(dst_primes)
-    bh = np.zeros((nd, k8, 1), np.uint32)
-    b = np.full((nd, k8, 1), _PAD_MOD, np.uint32)
-    binv = np.full((nd, k8, 1), mm.MontConstants(_PAD_MOD).qinv_neg, np.uint32)
-    w = np.zeros((nd, k8, m), np.uint32)
+    dst = np.array(dst_primes, np.uint64)
+    dsc = np.zeros((nd, k, _k.NDSC), np.uint32)
+    dsc[..., _k.DB] = _PAD_MOD
+    dsc[..., _k.DBINV] = mm.MontConstants(_PAD_MOD).qinv_neg
+    wm = np.zeros((nd, k, len(dst)), np.uint32)
     for j, src in enumerate(digits):
-        k = len(src)
+        n = len(src)
         bhat_inv, wj = rns.bconv_tables(src, tuple(int(c) for c in dst_primes))
-        for i, bi in enumerate(src):
-            bh[j, i, 0] = (int(bhat_inv[i]) << 32) % int(bi)
-        b[j, :k, 0] = np.array(src, np.uint32)
-        binv[j, :k, 0] = mm.mont_constants_array(list(src))["qinv_neg"]
-        w[j, :k] = wj
-    return bh, b, binv, w
-
-
-def _plan_arrays(plan):
-    m = plan.num_limbs
-    return dict(
-        twa=jnp.asarray(plan.twa_mont),
-        v2=jnp.asarray(plan.v2_limbs),
-        v1=jnp.asarray(plan.v1_limbs),
-        t=jnp.asarray(plan.t_mont),
-        cm=jnp.asarray(plan.c_mont),
-        q=jnp.asarray(plan.qs.reshape(m, 1)),
-        qinv=jnp.asarray(plan.qinv_neg.reshape(m, 1)),
-        r2=jnp.asarray(plan.r2.reshape(m, 1)),
-    )
+        dsc[j, :n, _k.DB] = np.array(src, np.uint32)
+        dsc[j, :n, _k.DBINV] = mm.mont_constants_array(list(src))["qinv_neg"]
+        dsc[j, :n, _k.DBH] = [(int(bhat_inv[i]) << 32) % int(b) for i, b in enumerate(src)]
+        wm[j, :n] = (np.asarray(wj, np.uint64) << np.uint64(32)) % dst
+    return jnp.asarray(dsc.reshape(-1)), jnp.asarray(wm.reshape(-1))
 
 
 @functools.lru_cache(maxsize=256)
 def ks_tables(params: CkksParams, level: int) -> KsTables:
     alpha = params.alpha
+    _check_fits(alpha, params.n)
     beta = params.beta(level)
     ext = poly.ext_idx(params, level)
-    dst = poly.primes_for(params, ext)
-    k8 = _pad8(alpha)
     spans, digits = [], []
     for j in range(beta):
         lo, hi = j * alpha, min((j + 1) * alpha, level + 1)
         spans.append((lo, hi))
         digits.append(poly.primes_for(params, tuple(range(lo, hi))))
-    bh, b, binv, w = _prescale_tables(digits, dst, k8)
     plan = poly.plan_for(params, ext)
+    dsc, wm = _prescale_tables(digits, poly.primes_for(params, ext), alpha)
     return KsTables(
-        beta=beta, k8=k8, m=len(ext), n1=plan.n1, n2=plan.n2, spans=tuple(spans),
-        bh=jnp.asarray(bh), b=jnp.asarray(b), binv=jnp.asarray(binv), w=jnp.asarray(w),
-        **_plan_arrays(plan),
+        beta=beta, k=alpha, m=len(ext), spans=tuple(spans), dsc=dsc, wm=wm,
+        ntt=ntt_ops.kernel_tables(plan, len(ext), inverse=False),
     )
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(frozen=True)
 class ModDownTables:
-    k8: int
+    k: int
     m: int
-    n1: int
-    n2: int
-    bh: jnp.ndarray
-    b: jnp.ndarray
-    binv: jnp.ndarray
-    w: jnp.ndarray
-    pinv: jnp.ndarray  # (m, 1) Montgomery [P⁻¹]_{q_e}
-    twa: jnp.ndarray
-    v2: jnp.ndarray
-    v1: jnp.ndarray
-    t: jnp.ndarray
-    cm: jnp.ndarray
-    q: jnp.ndarray
-    qinv: jnp.ndarray
-    r2: jnp.ndarray
+    dsc: jnp.ndarray
+    wm: jnp.ndarray
+    pinv: jnp.ndarray  # (m,) Montgomery [P⁻¹]_{q_e}
+    ntt: ntt_ops.KernelTables  # forward NTT over the q basis
 
 
 @functools.lru_cache(maxsize=256)
 def moddown_tables(params: CkksParams, level: int) -> ModDownTables:
     p_primes = poly.primes_for(params, poly.p_idx(params))
+    _check_fits(len(p_primes), params.n)
     q_primes = poly.primes_for(params, poly.q_idx(params, level))
-    k8 = _pad8(len(p_primes))
-    bh, b, binv, w = _prescale_tables([p_primes], q_primes, k8)
-    P = rns.product(p_primes)
-    pinv = np.array(
-        [(pow(P % int(q), -1, int(q)) << 32) % int(q) for q in q_primes], np.uint32
-    ).reshape(-1, 1)
     plan = poly.plan_for(params, poly.q_idx(params, level))
+    dsc, wm = _prescale_tables([p_primes], q_primes, len(p_primes))
+    P = rns.product(p_primes)
+    pinv = np.array([(pow(P % int(q), -1, int(q)) << 32) % int(q) for q in q_primes], np.uint32)
     return ModDownTables(
-        k8=k8, m=len(q_primes), n1=plan.n1, n2=plan.n2,
-        bh=jnp.asarray(bh[0]), b=jnp.asarray(b[0]), binv=jnp.asarray(binv[0]),
-        w=jnp.asarray(w[0]), pinv=jnp.asarray(pinv), **_plan_arrays(plan),
+        k=len(p_primes), m=len(q_primes), dsc=dsc, wm=wm, pinv=jnp.asarray(pinv),
+        ntt=ntt_ops.kernel_tables(plan, len(q_primes), inverse=False),
     )
 
 
 def pack_digits(d_coeff, tb: KsTables, n: int):
-    """(nq, N) coefficient limbs → (β, k8, N) zero-padded digit blocks."""
-    xd = jnp.zeros((tb.beta, tb.k8, n), jnp.uint32)
+    """(nq, N) coefficient limbs → (β, k, N2, N1) zero-padded digit blocks."""
+    xd = jnp.zeros((tb.beta, tb.k, n), jnp.uint32)
     for j, (lo, hi) in enumerate(tb.spans):
         xd = xd.at[j, : hi - lo].set(d_coeff[lo:hi])
-    return xd
+    return xd.reshape(tb.beta, tb.k, tb.ntt.n2, tb.ntt.n1)
 
 
 def key_switch_digits(d_coeff, ksk_sel, params: CkksParams, level: int, backend: str = "auto"):
@@ -177,16 +141,17 @@ def key_switch_digits(d_coeff, ksk_sel, params: CkksParams, level: int, backend:
     eval-domain key limbs restricted to the active extended basis.
     Returns (acc0, acc1), each (m, N) uint32 eval-domain.
     """
-    if _resolve(backend) == "ref":
+    if tpu.resolve(backend) == "ref":
         return _ref.key_switch_digits_ref(d_coeff, ksk_sel, params, level)
     tb = ks_tables(params, level)
+    nt = tb.ntt
     xd = pack_digits(jnp.asarray(d_coeff, jnp.uint32), tb, params.n)
+    ksk = jnp.asarray(ksk_sel, jnp.uint32).reshape(tb.beta, 2, tb.m, nt.n1, nt.n2)
     dispatch.record("fusedks")
     out = _k.fused_ks_pallas(
-        xd, tb.bh, tb.b, tb.binv, tb.w, tb.twa, tb.v2, tb.v1, tb.t, tb.cm,
-        tb.q, tb.qinv, tb.r2, jnp.asarray(ksk_sel, jnp.uint32),
-        n1=tb.n1, n2=tb.n2, interpret=jax.default_backend() != "tpu",
-    )
+        xd, nt.sc, tb.dsc, tb.wm, nt.tw, nt.v2, nt.v1, nt.t, ksk,
+        interpret=not tpu.on_tpu(),
+    ).reshape(tb.m, 2, params.n)
     return out[:, 0], out[:, 1]
 
 
@@ -198,17 +163,16 @@ def mod_down_digits(p_coeff, q_part, params: CkksParams, level: int, backend: st
     C = 2 for one key-switch's accumulator pair; a hoisted rotation group
     passes C = 2·R to ModDown every rotation's pair in one launch.
     """
-    if _resolve(backend) == "ref":
+    if tpu.resolve(backend) == "ref":
         return _ref.mod_down_digits_ref(p_coeff, q_part, params, level)
     tb = moddown_tables(params, level)
-    alpha = params.alpha
+    nt = tb.ntt
     nb = p_coeff.shape[0]
-    pc = jnp.zeros((nb, tb.k8, params.n), jnp.uint32).at[:, :alpha].set(
-        jnp.asarray(p_coeff, jnp.uint32)
-    )
+    pc = jnp.asarray(p_coeff, jnp.uint32).reshape(nb, tb.k, nt.n2, nt.n1)
+    qp = jnp.asarray(q_part, jnp.uint32).reshape(nb, tb.m, nt.n1, nt.n2)
     dispatch.record("fused_moddown")
-    return _k.fused_moddown_pallas(
-        pc, tb.bh, tb.b, tb.binv, tb.w, tb.twa, tb.v2, tb.v1, tb.t, tb.cm,
-        tb.q, tb.qinv, jnp.asarray(q_part, jnp.uint32), tb.pinv,
-        n1=tb.n1, n2=tb.n2, interpret=jax.default_backend() != "tpu",
+    out = _k.fused_moddown_pallas(
+        pc, nt.sc, tb.dsc, tb.wm, tb.pinv, nt.tw, nt.v2, nt.v1, nt.t, qp,
+        interpret=not tpu.on_tpu(),
     )
+    return out.reshape(nb, tb.m, params.n)

@@ -19,8 +19,13 @@ Two kernels realise that split:
     turns the per-digit automorphism into a single post-ModDown permutation
     and keeps this kernel a pure Montgomery multiply-accumulate.
 
-Per-rotation work after hoisting is one (1, β, 2, 1, N) key stream + 2N MACs
-per extended limb — no NTT, no BConv.  The β forward NTTs of the ModUp are
+Scoped VMEM the TPU compiler reports for a v5e (blocks plus the body's
+scratch): ``hoist_modup`` 1.48 MiB at dblookup and 8.79 MiB at lstm (the
+fused shape rule of ``kernels.fusedks`` bounds it); ``hoist_mac`` over four
+rotations 1.84 / 6.21 MiB.  Both are inside the 16 MiB default limit.
+
+Per-rotation work after hoisting is one (β, 2, N) key stream + 2N MACs per
+extended limb — no NTT, no BConv.  The β forward NTTs of the ModUp are
 paid once per group instead of once per rotation: O(β + k) vs O(k·β).
 """
 
@@ -32,106 +37,84 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.fhe.ntt import NDIAG, NLIMB8
-from repro.kernels.fusedks.kernel import _ntt_fwd_inline, _prescale_bconv_row
-from repro.kernels.ntt.kernel import _montmul
+from repro.kernels import tpu
+from repro.kernels.fusedks.kernel import _modup_limb, _ntt_specs
+from repro.kernels.ntt.kernel import _addmod, _montmul, limb_scalars
 
 
-def _modup_body(
-    xd_ref, bh_ref, b_ref, binv_ref, w_ref, twa_ref, v2_ref, v1_ref, t_ref,
-    c_ref, q_ref, qinv_ref, o_ref, *, n1, n2,
-):
-    q = q_ref[0, 0]
-    qinv = qinv_ref[0, 0]
-    cm = c_ref[0]  # (NDIAG,)
-    y = _prescale_bconv_row(
-        xd_ref[0], bh_ref[0], b_ref[0], binv_ref[0], w_ref[0].T, cm, q, qinv
-    )
-    o_ref[0, 0] = _ntt_fwd_inline(
-        y.reshape(-1), twa_ref[0], v2_ref[0], v1_ref[0], t_ref[0], cm, q, qinv, n1, n2
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("n1", "n2", "interpret"))
-def hoist_modup_pallas(xd, bh, b, binv, w, twa, v2, v1, t, cm, q, qinv, *, n1, n2, interpret):
-    """Raise all β digits of one polynomial to the extended basis: ONE launch.
-
-    Same inputs as ``fusedks.fused_ks_pallas`` minus the key material:
-    xd (β, k8, N) zero-padded digit source limbs (coeff domain), per-digit
-    prescale constants, BConv weights, and the extended-basis NTT plan.
-    Returns (β, m, N) uint32 — the hoisted digits, eval domain, reusable by
-    every rotation of the group.
-    """
-    beta, k8, n = xd.shape
-    m = w.shape[2]
-    return pl.pallas_call(
-        functools.partial(_modup_body, n1=n1, n2=n2),
-        grid=(m, beta),
-        in_specs=[
-            pl.BlockSpec((1, k8, n), lambda e, j: (j, 0, 0)),  # xd
-            pl.BlockSpec((1, k8, 1), lambda e, j: (j, 0, 0)),  # bh
-            pl.BlockSpec((1, k8, 1), lambda e, j: (j, 0, 0)),  # b
-            pl.BlockSpec((1, k8, 1), lambda e, j: (j, 0, 0)),  # binv
-            pl.BlockSpec((1, k8, 1), lambda e, j: (j, 0, e)),  # w column e
-            pl.BlockSpec((1, n1, n2), lambda e, j: (e, 0, 0)),  # twist
-            pl.BlockSpec((1, NLIMB8, n2, n2), lambda e, j: (e, 0, 0, 0)),  # V2
-            pl.BlockSpec((1, NLIMB8, n1, n1), lambda e, j: (e, 0, 0, 0)),  # V1
-            pl.BlockSpec((1, n1, n2), lambda e, j: (e, 0, 0)),  # inter-step twiddle
-            pl.BlockSpec((1, NDIAG), lambda e, j: (e, 0)),  # diagonal mont consts
-            pl.BlockSpec((1, 1), lambda e, j: (e, 0)),  # q
-            pl.BlockSpec((1, 1), lambda e, j: (e, 0)),  # qinv_neg
-        ],
-        out_specs=pl.BlockSpec((1, 1, n), lambda e, j: (j, e, 0)),
-        out_shape=jax.ShapeDtypeStruct((beta, m, n), jnp.uint32),
-        interpret=interpret,
-    )(xd, bh, b, binv, w, twa, v2, v1, t, cm, q, qinv)
-
-
-def _mac_body(dig_ref, ksk_ref, q_ref, qinv_ref, r2_ref, o_ref, *, beta):
-    q = q_ref[0, 0]
-    qinv = qinv_ref[0, 0]
-    r2 = r2_ref[0, 0]
-    acc0 = acc1 = None
-    for j in range(beta):  # β is static — the loop unrolls inside one program
-        x = dig_ref[j, 0]
-        t0 = _montmul(_montmul(x, ksk_ref[0, j, 0, 0], q, qinv), r2, q, qinv)
-        t1 = _montmul(_montmul(x, ksk_ref[0, j, 1, 0], q, qinv), r2, q, qinv)
-        if acc0 is None:
-            acc0, acc1 = t0, t1
-        else:
-            s0 = acc0 + t0
-            acc0 = jnp.where(s0 >= q, s0 - q, s0)
-            s1 = acc1 + t1
-            acc1 = jnp.where(s1 >= q, s1 - q, s1)
-    o_ref[0, 0, 0] = acc0
-    o_ref[0, 1, 0] = acc1
+def _modup_body(sc_ref, dsc_ref, wm_ref, xd_ref, twa_ref, v2_ref, v1_ref, t_ref, o_ref):
+    e, j = pl.program_id(0), pl.program_id(1)
+    o_ref[...] = _modup_limb(
+        sc_ref, dsc_ref, wm_ref, xd_ref, twa_ref, v2_ref, v1_ref, t_ref, j, e
+    )[0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def hoist_mac_pallas(dig, ksk, q, qinv, r2, *, interpret):
+def hoist_modup_pallas(xd, sc, dsc, wm, twa, v2, v1, t, *, interpret):
+    """Raise all β digits of one polynomial to the extended basis: ONE launch.
+
+    Same inputs as ``fusedks.fused_ks_pallas`` minus the key material:
+    xd (β, k, N2, N1) zero-padded digit source limbs (coeff domain), the flat
+    SMEM scalar tables, and the extended-basis NTT tables.
+    Returns (β, m, N1, N2) uint32 — the hoisted digits, eval domain, reusable
+    by every rotation of the group.
+    """
+    beta, k, n2, n1 = xd.shape
+    m = twa.shape[0]
+    return tpu.call(
+        _modup_body,
+        (sc, dsc, wm, xd, twa, v2, v1, t),
+        grid=(m, beta),
+        in_specs=[tpu.smem(), tpu.smem(), tpu.smem(),
+                  pl.BlockSpec((None, k, n2, n1), lambda e, j: (j, 0, 0, 0))]
+        + _ntt_specs(n1, n2, lambda e, j: e),
+        out_specs=pl.BlockSpec((None, None, n1, n2), lambda e, j: (j, e, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((beta, m, n1, n2), jnp.uint32),
+        interpret=interpret,
+    )
+
+
+def _mac_body(sc_ref, dig_ref, ksk_ref, o_ref):
+    q, qinv, r2, _ = limb_scalars(sc_ref, pl.program_id(0))
+    acc0 = acc1 = None
+    for j in range(dig_ref.shape[0]):  # β is static — the loop unrolls inside one program
+        x = dig_ref[j]
+        t0 = _montmul(_montmul(x, ksk_ref[j, 0], q, qinv), r2, q, qinv)
+        t1 = _montmul(_montmul(x, ksk_ref[j, 1], q, qinv), r2, q, qinv)
+        if acc0 is None:
+            acc0, acc1 = t0, t1
+        else:
+            acc0 = _addmod(acc0, t0, q)
+            acc1 = _addmod(acc1, t1, q)
+    o_ref[0] = acc0
+    o_ref[1] = acc1
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def hoist_mac_pallas(dig, ksk, sc, *, interpret):
     """Every rotation of one hoisted group in a single launch.
 
-    dig: (β, m, N) hoisted digits (eval domain, extended basis) — the limb-e
-         block is VMEM-resident across all R rotations (r is the inner grid
-         axis, so its block index is constant while r sweeps);
-    ksk: (R, β, 2, m, N) σ_t^{-1}-pre-permuted switching-key limbs;
-    q/qinv/r2: (m, 1) extended-basis Montgomery constants.
-    Returns (R, 2, m, N): one MAC accumulator pair per rotation, still in the
-    σ_t^{-1} frame (the caller ModDowns, then applies the permutation once).
+    dig: (β, m, N1, N2) hoisted digits (eval domain, extended basis) — the
+         limb-e block is VMEM-resident across all R rotations (r is the inner
+         grid axis, so its block index is constant while r sweeps);
+    ksk: (R, β, 2, m, N1, N2) σ_t^{-1}-pre-permuted switching-key limbs;
+    sc:  (m·NSC,) extended-basis limb scalars (SMEM).
+    Returns (R, 2, m, N1, N2): one MAC accumulator pair per rotation, still
+    in the σ_t^{-1} frame (the caller ModDowns, then applies the permutation
+    once).
     """
-    beta, m, n = dig.shape
+    beta, m, n1, n2 = dig.shape
     nrot = ksk.shape[0]
-    return pl.pallas_call(
-        functools.partial(_mac_body, beta=beta),
+    return tpu.call(
+        _mac_body,
+        (sc, dig, ksk),
         grid=(m, nrot),
         in_specs=[
-            pl.BlockSpec((beta, 1, n), lambda e, r: (0, e, 0)),  # dig (resident per e)
-            pl.BlockSpec((1, beta, 2, 1, n), lambda e, r: (r, 0, 0, e, 0)),  # ksk
-            pl.BlockSpec((1, 1), lambda e, r: (e, 0)),  # q
-            pl.BlockSpec((1, 1), lambda e, r: (e, 0)),  # qinv_neg
-            pl.BlockSpec((1, 1), lambda e, r: (e, 0)),  # r2
+            tpu.smem(),
+            pl.BlockSpec((beta, None, n1, n2), lambda e, r: (0, e, 0, 0)),  # resident per e
+            pl.BlockSpec((None, beta, 2, None, n1, n2), lambda e, r: (r, 0, 0, e, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 2, 1, n), lambda e, r: (r, 0, e, 0)),
-        out_shape=jax.ShapeDtypeStruct((nrot, 2, m, n), jnp.uint32),
+        out_specs=pl.BlockSpec((None, 2, None, n1, n2), lambda e, r: (r, 0, e, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nrot, 2, m, n1, n2), jnp.uint32),
         interpret=interpret,
-    )(dig, ksk, q, qinv, r2)
+    )

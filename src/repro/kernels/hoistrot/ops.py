@@ -5,7 +5,7 @@
 Galois key of a rotation group against those digits in a single launch.
 Backends follow the repo convention:
 
-  * "kernel" — the Pallas pipelines (interpret=True off-TPU);
+  * "kernel" — the Pallas pipelines (interpreted off-TPU);
   * "ref"    — staged u64 oracle in ``ref``;
   * "auto"   — kernel on TPU, ref elsewhere.
 
@@ -17,22 +17,14 @@ weights, extended-basis NTT plan) are the same cache.
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
-from repro.fhe import poly
 from repro.fhe.params import CkksParams
-from repro.kernels import dispatch
+from repro.kernels import dispatch, tpu
 from repro.kernels.fusedks import ops as fused_ops
 
 from . import kernel as _k
 from . import ref as _ref
-
-
-def _resolve(backend: str) -> str:
-    if backend == "auto":
-        return "kernel" if jax.default_backend() == "tpu" else "ref"
-    return backend
 
 
 def mod_up_digits(d_coeff, params: CkksParams, level: int, backend: str = "auto"):
@@ -42,15 +34,16 @@ def mod_up_digits(d_coeff, params: CkksParams, level: int, backend: str = "auto"
     eval-domain digits over the extended basis — the reusable ModUp half of a
     key-switch (rotation-independent, shared by a whole hoisted group).
     """
-    if _resolve(backend) == "ref":
+    if tpu.resolve(backend) == "ref":
         return _ref.mod_up_digits_ref(d_coeff, params, level)
     tb = fused_ops.ks_tables(params, level)
+    nt = tb.ntt
     xd = fused_ops.pack_digits(jnp.asarray(d_coeff, jnp.uint32), tb, params.n)
     dispatch.record("hoistmodup")
-    return _k.hoist_modup_pallas(
-        xd, tb.bh, tb.b, tb.binv, tb.w, tb.twa, tb.v2, tb.v1, tb.t, tb.cm,
-        tb.q, tb.qinv, n1=tb.n1, n2=tb.n2, interpret=jax.default_backend() != "tpu",
+    out = _k.hoist_modup_pallas(
+        xd, nt.sc, tb.dsc, tb.wm, nt.tw, nt.v2, nt.v1, nt.t, interpret=not tpu.on_tpu(),
     )
+    return out.reshape(tb.beta, tb.m, params.n)
 
 
 def galois_mac(dig, ksk, params: CkksParams, level: int, backend: str = "auto",
@@ -65,14 +58,16 @@ def galois_mac(dig, ksk, params: CkksParams, level: int, backend: str = "auto",
     """
     if staged:
         return _ref.galois_mac_ref(dig, ksk, params, level, stage=backend)
-    if _resolve(backend) == "ref":
+    if tpu.resolve(backend) == "ref":
         return _ref.galois_mac_ref(dig, ksk, params, level)
-    plan = poly.plan_for(params, poly.ext_idx(params, level))
-    m = plan.num_limbs
+    tb = fused_ops.ks_tables(params, level)
+    nt = tb.ntt
+    beta, m, n = dig.shape
+    nrot = ksk.shape[0]
     dispatch.record("hoistmac")
-    return _k.hoist_mac_pallas(
-        jnp.asarray(dig, jnp.uint32), jnp.asarray(ksk, jnp.uint32),
-        jnp.asarray(plan.qs.reshape(m, 1)), jnp.asarray(plan.qinv_neg.reshape(m, 1)),
-        jnp.asarray(plan.r2.reshape(m, 1)),
-        interpret=jax.default_backend() != "tpu",
+    out = _k.hoist_mac_pallas(
+        jnp.asarray(dig, jnp.uint32).reshape(beta, m, nt.n1, nt.n2),
+        jnp.asarray(ksk, jnp.uint32).reshape(nrot, beta, 2, m, nt.n1, nt.n2),
+        nt.sc, interpret=not tpu.on_tpu(),
     )
+    return out.reshape(nrot, 2, m, n)

@@ -3,28 +3,30 @@
 from __future__ import annotations
 
 import functools
-import math
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.fhe import modmath as mm
-from repro.kernels import dispatch
+from repro.kernels import dispatch, tpu
 
 from . import kernel as _k
 from . import ref as _ref
 
 
-def _resolve(backend):
-    if backend == "auto":
-        return "kernel" if jax.default_backend() == "tpu" else "ref"
-    return backend
-
-
 @functools.lru_cache(maxsize=1024)
 def _mont_cached(qs: tuple[int, ...]) -> dict:
     return mm.mont_constants_array(list(qs))
+
+
+def _launch(kernel, a, b, consts):
+    """Run a row kernel over (..., l, N) operands: one row per (batch, limb),
+    each per-limb constant tiled over the flattened leading batch."""
+    l, n = a.shape[-2:]
+    reps = a.size // (l * n)
+    rows = [jnp.tile(jnp.asarray(c, jnp.uint32).reshape(-1), reps) for c in consts]
+    out = kernel(a.reshape(-1, n), b.reshape(-1, n), *rows, interpret=not tpu.on_tpu())
+    return out.reshape(a.shape)
 
 
 def pointwise_mulmod(a, b, qs, qinv=None, r2=None, backend: str = "auto"):
@@ -34,40 +36,23 @@ def pointwise_mulmod(a, b, qs, qinv=None, r2=None, backend: str = "auto"):
     does not supply them, so any call site can reach the kernel path.
     """
     dispatch.record("mulmod")
-    if _resolve(backend) == "ref":
+    if tpu.resolve(backend) == "ref":
         return _ref.mulmod_ref(a, b, jnp.asarray(qs, jnp.uint32))
     if qinv is None or r2 is None:
         consts = _mont_cached(tuple(int(q) for q in np.asarray(qs).tolist()))
         qinv, r2 = consts["qinv_neg"], consts["r2"]
-    lead = a.shape[:-2]
-    l, n = a.shape[-2:]
-    reps = math.prod(lead) if lead else 1
-    q = jnp.tile(jnp.asarray(qs, jnp.uint32).reshape(-1, 1), (reps, 1))
-    qi = jnp.tile(jnp.asarray(qinv, jnp.uint32).reshape(-1, 1), (reps, 1))
-    r2_ = jnp.tile(jnp.asarray(r2, jnp.uint32).reshape(-1, 1), (reps, 1))
-    out = _k.mulmod_pallas(a.reshape(-1, n), b.reshape(-1, n), q, qi, r2_, interpret=jax.default_backend() != "tpu")
-    return out.reshape(lead + (l, n))
+    return _launch(_k.mulmod_pallas, a, b, (qs, qinv, r2))
 
 
 def pointwise_addmod(a, b, qs, backend: str = "auto"):
     dispatch.record("addmod")
-    if _resolve(backend) == "ref":
+    if tpu.resolve(backend) == "ref":
         return _ref.addmod_ref(a, b, jnp.asarray(qs, jnp.uint32))
-    lead = a.shape[:-2]
-    l, n = a.shape[-2:]
-    reps = math.prod(lead) if lead else 1
-    q = jnp.tile(jnp.asarray(qs, jnp.uint32).reshape(-1, 1), (reps, 1))
-    out = _k.addmod_pallas(a.reshape(-1, n), b.reshape(-1, n), q, interpret=jax.default_backend() != "tpu")
-    return out.reshape(lead + (l, n))
+    return _launch(_k.addmod_pallas, a, b, (qs,))
 
 
 def pointwise_submod(a, b, qs, backend: str = "auto"):
     dispatch.record("submod")
-    if _resolve(backend) == "ref":
+    if tpu.resolve(backend) == "ref":
         return _ref.submod_ref(a, b, jnp.asarray(qs, jnp.uint32))
-    lead = a.shape[:-2]
-    l, n = a.shape[-2:]
-    reps = math.prod(lead) if lead else 1
-    q = jnp.tile(jnp.asarray(qs, jnp.uint32).reshape(-1, 1), (reps, 1))
-    out = _k.submod_pallas(a.reshape(-1, n), b.reshape(-1, n), q, interpret=jax.default_backend() != "tpu")
-    return out.reshape(lead + (l, n))
+    return _launch(_k.submod_pallas, a, b, (qs,))

@@ -74,17 +74,15 @@ def _pod_compress(grads, mesh: Mesh):
     batch is pod-sharded; for the explicit-compression path we instead mark
     the batch pod-replicated and do the pod reduction ourselves in int8.
     """
-    from jax.experimental.shard_map import shard_map
-
     spec = P()  # gradients handled as pod-replicated blocks per shard
 
     def red(g):
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda x: compress.compressed_psum_mean(x, "pod"),
             mesh=mesh,
             in_specs=P("pod"),
             out_specs=P("pod"),
-            check_rep=False,
+            check_vma=False,
         )
         flat = g.reshape(-1)
         n = flat.shape[0]

@@ -1,5 +1,10 @@
 """Test-environment shims.
 
+Pins JAX to the CPU before anything imports it: every Pallas kernel then runs
+in the Pallas interpreter, bit-exact against the uint64 oracle, on any host.
+The on-chip check is ``chip_smoke.py``; ``test_tpu_compile.py`` compiles the
+kernels for a described TPU without one.
+
 Provides a minimal deterministic fallback for ``hypothesis`` when the real
 package is not installed (`pip install -e .[dev]` brings the real one).  The
 fallback drives each ``@given`` test with seeded pseudo-random examples —
@@ -17,6 +22,8 @@ import os
 import random
 import sys
 import types
+
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 try:
     import hypothesis  # noqa: F401  (real package wins when installed)

@@ -178,3 +178,16 @@ def test_simulator_accounts_fused_stream(ks_setup):
         i.limbs * i.n * chip.word_bytes for i in ts if i.op in BOUNDARY
     )
     assert rs.hbm_bytes - rf.hbm_bytes == pytest.approx(extra)
+
+
+# ---------------------------------------------------------------------------
+# the fused shape rule: digits over the scoped VMEM limit are refused loudly
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("preset", ["resnet20", "packed_bootstrap"])
+def test_fused_tables_refuse_shapes_over_vmem(preset):
+    p = P.workload_params(preset)  # dnum=1: one 42- or 58-row digit at N=2^16
+    for build in (fops.ks_tables, fops.moddown_tables):
+        with pytest.raises(ValueError, match="backend='staged'"):
+            build(p, p.L)

@@ -42,6 +42,7 @@ import dataclasses
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import dispatch
 from repro.kernels.modops import ops as mo
 
 from . import keyswitch, poly, rns, trace
@@ -304,8 +305,8 @@ def _mod_switch(ctx, ct: BgvCiphertext) -> BgvCiphertext:
     bk = ctx.stage
     tinv = pow(t, -1, q_last)
     qinv = np.array([pow(q_last % int(q), -1, int(q)) for q in rem_primes], np.uint64)
-    qinv_b = jnp.asarray(qinv[:, None].astype(np.uint32))
-    qs_rem_i64 = jnp.asarray(qs_rem.astype(np.int64))[:, None]
+    qinv_b = dispatch.upload(qinv[:, None], np.uint32)
+    qs_rem_i64 = dispatch.upload(qs_rem[:, None], np.int64)
 
     def _one(c):
         # iNTT the dropped limb, twist by t^{-1}, centre, re-scale by t — the

@@ -13,8 +13,7 @@ object bundling the three things an evaluation needs:
   * ``ExecPolicy``  — *how* to execute: kernel backend, hoisting mode, the
                       numerics mode (future: double-hoisting keeps BSGS inner
                       products in the extended basis — not bit-exact, so it is
-                      a policy field, not a kwarg), and an optional
-                      dispatch-counter hook observing every kernel launch.
+                      a policy field, not a kwarg).
 
 Ops are implemented ONCE, against a context (the ``_impl`` functions in
 ``ops``/``linear``/``bootstrap``/``polyeval``); the legacy module-level free
@@ -41,8 +40,6 @@ Quick use::
 from __future__ import annotations
 
 import dataclasses
-import functools
-from typing import Callable
 
 from repro.kernels import dispatch
 
@@ -72,17 +69,13 @@ class ExecPolicy:
     ``policy_key()`` is the canonical cache identity — two policies with equal
     keys are guaranteed to produce identical instruction streams and cycle
     counts, and distinct (scheme, backend, hoisting, numerics) tuples never
-    alias.  ``dispatch_hook`` is deliberately NOT part of the key (or of
-    equality): observing kernel launches cannot change what is launched.
+    alias.
     """
 
     backend: str = "auto"  # kernel pipeline: fused | kernel | staged | ref | auto
     hoisting: str = "auto"  # rotation key-switch shape: never | auto | always
     numerics: str = "standard"  # exactness class (future: double_hoist)
     scheme: str = "ckks"  # which scheme's op expansions run: ckks | bgv
-    dispatch_hook: Callable[[str], None] | None = dataclasses.field(
-        default=None, compare=False
-    )
 
     def __post_init__(self):
         if self.backend not in BACKENDS:
@@ -102,8 +95,7 @@ class ExecPolicy:
         """Hashable identity for memo keys (serving service times, planner
         stream caches).  The scheme leads: a BGV and a CKKS job with otherwise
         identical knobs run different op expansions and must never share a
-        cached service time.  Excludes ``dispatch_hook`` — hooks observe
-        execution, they never change it."""
+        cached service time."""
         return (self.scheme, self.backend, self.hoisting, self.numerics)
 
     def replace(self, **changes) -> "ExecPolicy":
@@ -114,26 +106,6 @@ class ExecPolicy:
         matches) — the serving layer derives per-job effective policies this
         way, so one engine can price mixed CKKS+BGV traffic distinctly."""
         return self if scheme == self.scheme else dataclasses.replace(self, scheme=scheme)
-
-    def traced(self, tracer) -> "ExecPolicy":
-        """This policy with its kernel launches recorded into ``tracer`` (an
-        ``repro.obs.Tracer``): each dispatch becomes a unit-width slice at its
-        dispatch index (kernels have no sim-time of their own).  Composes with
-        an existing hook — both observe every launch.  A disabled tracer (or
-        None) returns ``self`` unchanged, preserving the zero-overhead rule.
-        ``policy_key`` ignores hooks, so the traced policy prices identically.
-        """
-        if tracer is None or not tracer:
-            return self
-        traced_hook = tracer.dispatch_hook()
-        prior = self.dispatch_hook
-        if prior is None:
-            hook = traced_hook
-        else:
-            def hook(op: str) -> None:
-                prior(op)
-                traced_hook(op)
-        return dataclasses.replace(self, dispatch_hook=hook)
 
     # -- resolved views -----------------------------------------------------
 
@@ -155,18 +127,9 @@ class ExecPolicy:
         return self.hoisting != "never"
 
 
-def _hooked(fn):
-    """Run a context method under the policy's dispatch-counter hook."""
-
-    @functools.wraps(fn)
-    def wrapper(self, *args, **kwargs):
-        hook = self.policy.dispatch_hook
-        if hook is None:
-            return fn(self, *args, **kwargs)
-        with dispatch.hook_dispatches(hook):
-            return fn(self, *args, **kwargs)
-
-    return wrapper
+def _spanned(fn):
+    """Run a context method under its ``fhe.<method>`` host span."""
+    return dispatch.spanned(f"fhe.{fn.__name__}")(fn)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -237,35 +200,35 @@ class FheContext:
 
     # -- encode / encrypt / decrypt -----------------------------------------
 
-    @_hooked
+    @_spanned
     def encode(self, z, level: int | None = None, scale: float | None = None):
         if self.scheme == "bgv":
             return _bgv._encode(self, z, level)
         return ops._encode(self, z, level, scale)
 
-    @_hooked
+    @_spanned
     def encode_const(self, c, level: int, scale: float) -> "ops.Plaintext":
         return ops._encode_const(self, c, level, scale)
 
-    @_hooked
+    @_spanned
     def decode(self, pt):
         if self.scheme == "bgv":
             return _bgv._decode(self, pt)
         return ops._decode(self, pt)
 
-    @_hooked
+    @_spanned
     def encrypt(self, pt, seed: int = 17):
         if self.scheme == "bgv":
             return _bgv._encrypt(self, self.require_keys().pk, pt, seed)
         return ops._encrypt(self, self.require_keys().pk, pt, seed)
 
-    @_hooked
+    @_spanned
     def decrypt(self, ct):
         if self.scheme == "bgv":
             return _bgv._decrypt(self, self.require_keys().sk, ct)
         return ops._decrypt(self, self.require_keys().sk, ct)
 
-    @_hooked
+    @_spanned
     def decrypt_decode(self, ct):
         sk = self.require_keys().sk
         if self.scheme == "bgv":
@@ -274,50 +237,51 @@ class FheContext:
 
     # -- additive ops -------------------------------------------------------
 
-    @_hooked
+    @_spanned
     def add(self, a, b):
         if self.scheme == "bgv":
             return _bgv._add(self, a, b)
         return ops._add(self, a, b)
 
-    @_hooked
+    @_spanned
     def sub(self, a, b):
         if self.scheme == "bgv":
             return _bgv._sub(self, a, b)
         return ops._sub(self, a, b)
 
-    @_hooked
+    @_spanned
     def negate(self, a):
         if self.scheme == "bgv":
             return _bgv._negate(self, a)
         return ops._negate(self, a)
 
-    @_hooked
+    @_spanned
     def add_plain(self, a, pt):
         return ops._add_plain(self, a, pt)
 
-    @_hooked
+    @_spanned
     def add_const(self, a, c):
         return ops._add_const(self, a, c)
 
+    @_spanned
     def level_drop(self, ct, level: int):
         return ops.level_drop(ct, level)
 
     # -- multiplicative ops -------------------------------------------------
 
-    @_hooked
+    @_spanned
     def mul_plain(self, a, pt, rescale_after: bool = True):
         return ops._mul_plain(self, a, pt, rescale_after)
 
-    @_hooked
+    @_spanned
     def mul_const(self, a, c, rescale_after: bool = True):
         return ops._mul_const(self, a, c, rescale_after)
 
-    @_hooked
+    @_spanned
     def mul_const_exact(self, a, c, target_scale: float):
         return ops._mul_const_exact(self, a, c, target_scale)
 
-    @_hooked
+    @_spanned
     def mul(self, a, b, rlk: SwitchingKey | None = None, rescale_after: bool = True):
         """Ciphertext-ciphertext multiplication with relinearisation.  Under a
         BGV context, ``rescale_after`` means "modulus-switch one level down
@@ -327,20 +291,20 @@ class FheContext:
             return _bgv._mul(self, a, b, rlk, mod_switch_after=rescale_after)
         return ops._mul(self, a, b, rlk, rescale_after)
 
-    @_hooked
+    @_spanned
     def square(self, a, rlk: SwitchingKey | None = None, rescale_after: bool = True):
         rlk = rlk if rlk is not None else self.require_keys().rlk
         if self.scheme == "bgv":
             return _bgv._mul(self, a, a, rlk, mod_switch_after=rescale_after)
         return ops._mul(self, a, a, rlk, rescale_after)
 
-    @_hooked
+    @_spanned
     def rescale(self, ct):
         if self.scheme == "bgv":
             raise ValueError("BGV has no rescale; use ctx.mod_switch(ct) instead")
         return ops._rescale(self, ct)
 
-    @_hooked
+    @_spanned
     def mod_switch(self, ct):
         """BGV modulus switch: drop the last chain prime, preserving the
         message mod t exactly (q_ℓ ≡ 1 mod t on the shared chain)."""
@@ -350,22 +314,22 @@ class FheContext:
 
     # -- rotations / conjugation --------------------------------------------
 
-    @_hooked
+    @_spanned
     def rotate(self, ct, r: int):
         """Cyclic slot rotation by r; the policy's hoisting mode picks the
         key-switch shape ("always" routes a single rotation through the
         hoisted path — bit-exact either way)."""
         return ops._rotate(self, ct, r, self.require_keys())
 
-    @_hooked
+    @_spanned
     def rotate_hoisted(self, ct, r: int, hoisted=None):
         return ops._rotate_hoisted(self, ct, r, self.require_keys(), hoisted)
 
-    @_hooked
+    @_spanned
     def rotate_hoisted_group(self, ct, rots) -> dict:
         return ops._rotate_hoisted_group(self, ct, rots, self.require_keys())
 
-    @_hooked
+    @_spanned
     def conjugate(self, ct):
         return ops._conjugate(self, ct, self.require_keys())
 
@@ -382,40 +346,40 @@ class FheContext:
             hoisting=self.policy.plan_hoist,
         )
 
-    @_hooked
+    @_spanned
     def apply_bsgs(self, ct, plan: "linear.BsgsPlan", scale: float | None = None):
         return linear._apply_bsgs(self, ct, plan, scale)
 
-    @_hooked
+    @_spanned
     def apply_bsgs_pair(self, ct, plans, scale: float | None = None):
         return (
             linear._apply_bsgs(self, ct, plans[0], scale),
             linear._apply_bsgs(self, ct, plans[1], scale),
         )
 
-    @_hooked
+    @_spanned
     def real_part(self, ct):
         return linear._real_part(self, ct)
 
-    @_hooked
+    @_spanned
     def imag_part(self, ct):
         return linear._imag_part(self, ct)
 
     # -- polynomial evaluation ----------------------------------------------
 
-    @_hooked
+    @_spanned
     def force_to(self, ct, level: int, scale: float):
         return polyeval._force_to(self, ct, level, scale)
 
-    @_hooked
+    @_spanned
     def add_any(self, a, b):
         return polyeval._add_any(self, a, b)
 
-    @_hooked
+    @_spanned
     def chebyshev_basis(self, x, degree: int) -> "polyeval.ChebyshevBasis":
         return polyeval.ChebyshevBasis(self, x, degree)
 
-    @_hooked
+    @_spanned
     def eval_poly(self, ct, coeffs, degree: int | None = None):
         """Σ c_i·T_i(ct) in the Chebyshev basis (exact scale discipline)."""
         import numpy as np
@@ -424,31 +388,31 @@ class FheContext:
         basis = polyeval.ChebyshevBasis(self, ct, degree)
         return polyeval._eval_chebyshev(self, basis, coeffs)
 
-    @_hooked
+    @_spanned
     def eval_chebyshev(self, basis: "polyeval.ChebyshevBasis", coeffs):
         return polyeval._eval_chebyshev(self, basis, coeffs)
 
     # -- bootstrapping -------------------------------------------------------
 
-    @_hooked
+    @_spanned
     def bootstrap(self, bctx: "_bootstrap.BootstrapContext", ct, post_scale: float | None = None):
         """Refresh an exhausted ciphertext through ``bctx``'s precomputed
         plans/keys under THIS context's execution policy."""
         return _bootstrap._bootstrap(self._bootstrap_ctx(bctx), bctx, ct, post_scale)
 
-    @_hooked
+    @_spanned
     def mod_raise(self, bctx, ct):
         return _bootstrap._mod_raise(self._bootstrap_ctx(bctx), bctx, ct)
 
-    @_hooked
+    @_spanned
     def coeff_to_slot(self, bctx, ct):
         return _bootstrap._coeff_to_slot(self._bootstrap_ctx(bctx), bctx, ct)
 
-    @_hooked
+    @_spanned
     def eval_mod(self, bctx, ct, coeff_scale: float):
         return _bootstrap._eval_mod(self._bootstrap_ctx(bctx), bctx, ct, coeff_scale)
 
-    @_hooked
+    @_spanned
     def slot_to_coeff(self, bctx, a0, a1):
         return _bootstrap._slot_to_coeff(self._bootstrap_ctx(bctx), bctx, a0, a1)
 

@@ -29,7 +29,9 @@ Two pipeline shapes execute the same math:
   "auto"           → fused on TPU, staged-ref elsewhere (CPU tests stay fast).
 
 Every stage records trace instructions; this function *is* the workload the
-bootstrappable clusters are shaped around.
+bootstrappable clusters are shaped around.  Each stage entry point
+runs under a ``ks.<stage>`` host span on the profiler's clock
+(``repro.kernels.dispatch``).
 """
 
 from __future__ import annotations
@@ -39,8 +41,9 @@ import functools
 
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
-from repro.kernels import tpu
+from repro.kernels import dispatch, tpu
 from repro.kernels.bconv import ops as bconv_ops
 from repro.kernels.fusedks import ops as fused_ops
 from repro.kernels.hoistrot import ops as hoist_ops
@@ -74,6 +77,7 @@ def _boundary(n: int, limbs: int) -> None:
 
 
 @functools.lru_cache(maxsize=2048)
+@dispatch.spanned("table.ks_digit")
 def _digit_tables(params: CkksParams, level: int, j: int):
     """(src_idx, bhat_inv, w, dst_primes) for digit j at ``level``."""
     digit_idx = tuple(i for i in params.digit(j) if i <= level)
@@ -81,33 +85,35 @@ def _digit_tables(params: CkksParams, level: int, j: int):
     dst_idx = poly.ext_idx(params, level)
     dst = poly.primes_for(params, dst_idx)
     bhat_inv, w = rns.bconv_tables(src, dst)
-    return digit_idx, jnp.asarray(bhat_inv), jnp.asarray(w), np.array(dst, np.uint64)
+    return digit_idx, dispatch.upload(bhat_inv), dispatch.upload(w), np.array(dst, np.uint64)
 
 
 @functools.lru_cache(maxsize=512)
+@dispatch.spanned("table.ks_moddown")
 def _moddown_tables(params: CkksParams, level: int):
     p_primes = poly.primes_for(params, poly.p_idx(params))
     q_primes = poly.primes_for(params, poly.q_idx(params, level))
     bhat_inv, w = rns.bconv_tables(p_primes, q_primes)
     P = rns.product(p_primes)
     pinv = np.array([pow(P % int(q), -1, int(q)) for q in q_primes], np.uint64)
-    return jnp.asarray(bhat_inv), jnp.asarray(w), np.array(q_primes, np.uint64), jnp.asarray(
-        pinv[:, None].astype(np.uint32)
-    )
+    return (dispatch.upload(bhat_inv), dispatch.upload(w), np.array(q_primes, np.uint64),
+            dispatch.upload(pinv[:, None], np.uint32))
 
 
 def _scale_limbs(x, consts, qs, backend):
     """x ∘ diag(consts) per limb — consts: (k,) broadcast over N."""
     trace.record("PMULT", x.shape[-1], x.shape[-2])
-    c = jnp.broadcast_to(jnp.asarray(consts, jnp.uint32)[:, None], x.shape)
+    c = jnp.broadcast_to(dispatch.upload(consts, np.uint32)[:, None], x.shape)
     return mo.pointwise_mulmod(x, c, qs, backend=backend)
 
 
 def _select_ksk(ksk: SwitchingKey, params: CkksParams, level: int, beta: int):
     """(β, 2, |ext|, N): key limbs restricted to active + special moduli."""
+    k = lax.slice_in_dim(ksk.k, 0, beta)
     return jnp.concatenate(
-        [ksk.k[:, :, : level + 1], ksk.k[:, :, params.L + 1 :]], axis=2
-    )[:beta]
+        [lax.slice_in_dim(k, 0, level + 1, axis=2),
+         lax.slice_in_dim(k, params.L + 1, None, axis=2)], axis=2
+    )
 
 
 def _record_fused_digits(params: CkksParams, level: int) -> None:
@@ -163,6 +169,7 @@ def mod_down(acc_ext, params: CkksParams, level: int, backend: str = "auto"):
     return mo.pointwise_mulmod(diff, pinv_b, q_np, backend=stage)
 
 
+@dispatch.spanned("ks.moddown")
 def mod_down_pair(acc0, acc1, params: CkksParams, level: int, backend: str = "auto"):
     """ModDown both MAC accumulators; fused path shares one kernel launch."""
     pipeline, stage = resolve_pipeline(backend)
@@ -174,12 +181,13 @@ def mod_down_pair(acc0, acc1, params: CkksParams, level: int, backend: str = "au
     nq = level + 1
     _record_fused_moddown(params, level)
     _record_fused_moddown(params, level)
-    p_part = jnp.stack([acc0[nq:], acc1[nq:]])
+    m = acc0.shape[0]
+    p_part = jnp.stack([poly.limbs(acc0, nq, m), poly.limbs(acc1, nq, m)])
     plan = poly.plan_for(params, poly.p_idx(params))
     p_coeff = ntt_ops.ntt_inv(p_part, plan, stage)
-    q_part = jnp.stack([acc0[:nq], acc1[:nq]])
+    q_part = jnp.stack([poly.limbs(acc0, 0, nq), poly.limbs(acc1, 0, nq)])
     out = fused_ops.mod_down_digits(p_coeff, q_part, params, level, backend="kernel")
-    return out[0], out[1]
+    return lax.index_in_dim(out, 0, keepdims=False), lax.index_in_dim(out, 1, keepdims=False)
 
 
 def key_switch(d_eval, params: CkksParams, level: int, ksk: SwitchingKey, backend: str = "auto"):
@@ -198,6 +206,7 @@ def key_switch_selected(d_eval, params: CkksParams, level: int, ksk_sel, backend
     return mod_down_pair(acc0, acc1, params, level, backend)
 
 
+@dispatch.spanned("ks.accumulate")
 def key_switch_accumulate(d_eval, params: CkksParams, level: int, ksk_sel,
                           backend: str = "auto"):
     """Stages 1–4 of a key switch: decompose d into digits and MAC against the
@@ -299,6 +308,7 @@ def _record_modup_digits(params: CkksParams, level: int) -> None:
         trace.record("NTT", n, m, fused=True)
 
 
+@dispatch.spanned("ks.modup")
 def hoisted_mod_up(d_eval, params: CkksParams, level: int, backend: str = "auto") -> HoistedDigits:
     """ModUp once: d (eval, q_0..q_ℓ) → reusable extended-basis digits.
 
@@ -365,6 +375,7 @@ def hoisted_ksk(params: CkksParams, keys: KeySet, t: int, level: int):
     return pre
 
 
+@dispatch.spanned("ks.mac")
 def hoisted_galois_ks(hd: HoistedDigits, ksk_stack, params: CkksParams, level: int,
                       backend: str = "auto"):
     """KSK inner products for a whole rotation group, σ_t^{-1} frame.
@@ -394,6 +405,7 @@ def hoisted_galois_ks(hd: HoistedDigits, ksk_stack, params: CkksParams, level: i
     )
 
 
+@dispatch.spanned("ks.moddown_group")
 def mod_down_group(accs, params: CkksParams, level: int, backend: str = "auto"):
     """ModDown every accumulator pair of a hoisted group.
 
@@ -410,10 +422,10 @@ def mod_down_group(accs, params: CkksParams, level: int, backend: str = "auto"):
     nq = level + 1
     for _ in range(2 * nrot):
         _record_fused_moddown(params, level)
-    p_part = accs[:, :, nq:].reshape(2 * nrot, params.alpha, params.n)
+    p_part = lax.slice_in_dim(accs, nq, None, axis=2).reshape(2 * nrot, params.alpha, params.n)
     plan = poly.plan_for(params, poly.p_idx(params))
     p_coeff = ntt_ops.ntt_inv(p_part, plan, _stage)
-    q_part = accs[:, :, :nq].reshape(2 * nrot, nq, params.n)
+    q_part = lax.slice_in_dim(accs, 0, nq, axis=2).reshape(2 * nrot, nq, params.n)
     out = fused_ops.mod_down_digits(p_coeff, q_part, params, level, backend="kernel")
     return out.reshape(nrot, 2, nq, params.n)
 
@@ -434,7 +446,7 @@ def permute_last(c0_eval, ks0, ks1, t: int, params: CkksParams, level: int,
     n = params.n
     qs = np.array(params.q_primes[: level + 1], np.uint64)
     trace.record("PADD", n, level + 1)
-    s0 = mo.pointwise_addmod(jnp.asarray(c0_eval, jnp.uint32), ks0, qs, backend=stage)
+    s0 = mo.pointwise_addmod(dispatch.upload(c0_eval, np.uint32), ks0, qs, backend=stage)
     return poly.automorphism_eval(s0, n, t), poly.automorphism_eval(ks1, n, t)
 
 

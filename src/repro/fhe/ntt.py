@@ -22,6 +22,8 @@ import functools
 import numpy as np
 from ml_dtypes import bfloat16
 
+from repro.kernels import dispatch
+
 from . import modmath as mm
 
 NLIMB8 = 4  # number of 8-bit limbs covering q < 2^31
@@ -213,6 +215,7 @@ _PER_LIMB_FIELDS = (
 
 
 @functools.lru_cache(maxsize=1024)
+@dispatch.spanned("table.subplan")
 def subplan(n: int, primes: tuple[int, ...], idx: tuple[int, ...]) -> NttPlan:
     """A view of build_plan(n, primes) restricted to the limb subset ``idx``.
 

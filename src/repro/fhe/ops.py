@@ -20,7 +20,9 @@ import dataclasses
 
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
+from repro.kernels import dispatch
 from repro.kernels.modops import ops as mo
 
 from . import encoder, keyswitch, poly, trace
@@ -138,7 +140,8 @@ def level_drop(ct: Ciphertext, level: int) -> Ciphertext:
     if level == ct.level:
         return ct
     assert level < ct.level
-    return Ciphertext(c0=ct.c0[: level + 1], c1=ct.c1[: level + 1], level=level, scale=ct.scale)
+    return Ciphertext(c0=poly.limbs(ct.c0, 0, level + 1), c1=poly.limbs(ct.c1, 0, level + 1),
+                      level=level, scale=ct.scale)
 
 
 def _add(ctx, a: Ciphertext, b: Ciphertext) -> Ciphertext:
@@ -186,7 +189,7 @@ def _add_plain(ctx, a: Ciphertext, pt: Plaintext) -> Ciphertext:
     qs = _qs(params, a.level)
     trace.record("PADD", params.n, a.level + 1)
     return Ciphertext(
-        c0=mo.pointwise_addmod(a.c0, pt.data[: a.level + 1], qs, backend=ctx.stage),
+        c0=mo.pointwise_addmod(a.c0, poly.limbs(pt.data, 0, a.level + 1), qs, backend=ctx.stage),
         c1=a.c1, level=a.level, scale=a.scale,
     )
 
@@ -207,7 +210,7 @@ def _mul_plain(ctx, a: Ciphertext, pt: Plaintext, rescale_after: bool = True) ->
     qs = _qs(params, a.level)
     bk = ctx.stage
     trace.record("PMULT", params.n, 2 * (a.level + 1))
-    d = pt.data[: a.level + 1]
+    d = poly.limbs(pt.data, 0, a.level + 1)
     out = Ciphertext(
         c0=mo.pointwise_mulmod(a.c0, d, qs, backend=bk),
         c1=mo.pointwise_mulmod(a.c1, d, qs, backend=bk),
@@ -273,18 +276,21 @@ def _rescale(ctx, ct: Ciphertext) -> Ciphertext:
     rem_primes = params.q_primes[:lv]
     bk = ctx.stage
     qinv = np.array([pow(q_last % int(q), -1, int(q)) for q in rem_primes], np.uint64)
-    qinv_b = jnp.asarray(qinv[:, None].astype(np.uint32))
+    qinv_b = dispatch.upload(qinv[:, None], np.uint32)
+    qs_col = dispatch.upload(qs_rem[:, None])
+    q_dev = dispatch.upload(q_last, np.uint64)
+    half = dispatch.upload(q_last // 2, np.uint64)
 
     def _one(c):
         # iNTT the dropped limb, re-embed its (centred) coefficients in every
         # remaining basis, NTT back, subtract, multiply by q_ℓ^{-1}.
-        last_coeff = poly.to_coeff(c[lv : lv + 1], params, (lv,), bk)
-        v = last_coeff[0].astype(jnp.uint64)
-        centered = jnp.where(v > q_last // 2, v + jnp.asarray(qs_rem[:, None]) - q_last, v)
-        rem = (centered % jnp.asarray(qs_rem[:, None])).astype(jnp.uint32)
+        last_coeff = poly.to_coeff(poly.limbs(c, lv, lv + 1), params, (lv,), bk)
+        v = lax.index_in_dim(last_coeff, 0, keepdims=False).astype(jnp.uint64)
+        centered = jnp.where(v > half, v + qs_col - q_dev, v)
+        rem = (centered % qs_col).astype(jnp.uint32)
         rem_eval = poly.to_eval(rem, params, poly.q_idx(params, lv - 1), bk)
         trace.record("PSUB", params.n, lv)
-        diff = mo.pointwise_submod(c[:lv], rem_eval, qs_rem, backend=bk)
+        diff = mo.pointwise_submod(poly.limbs(c, 0, lv), rem_eval, qs_rem, backend=bk)
         trace.record("PMULT", params.n, lv)
         return mo.pointwise_mulmod(diff, jnp.broadcast_to(qinv_b, diff.shape), qs_rem, backend=bk)
 

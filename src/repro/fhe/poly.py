@@ -15,7 +15,9 @@ import functools
 
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
+from repro.kernels import dispatch
 from repro.kernels.ntt import ops as ntt_ops
 
 from . import ntt as nttmod
@@ -41,6 +43,12 @@ def plan_for(params: CkksParams, idx: tuple[int, ...]) -> nttmod.NttPlan:
     return nttmod.subplan(params.n, params.all_primes, idx)
 
 
+def limbs(x, lo: int, hi: int):
+    """Limbs [lo, hi) of a (limbs, ...) array: a static slice, so the eager
+    call transfers no index to the device; the whole array is returned as is."""
+    return x if (lo, hi) == (0, x.shape[0]) else lax.slice_in_dim(x, lo, hi)
+
+
 def primes_for(params: CkksParams, idx: tuple[int, ...]) -> tuple[int, ...]:
     allp = params.all_primes
     return tuple(allp[i] for i in idx)
@@ -49,18 +57,19 @@ def primes_for(params: CkksParams, idx: tuple[int, ...]) -> tuple[int, ...]:
 def to_eval(x, params: CkksParams, idx: tuple[int, ...], backend: str = "auto"):
     """Coefficient → evaluation domain over the limb subset ``idx``."""
     trace.record("NTT", params.n, len(idx))
-    return ntt_ops.ntt_fwd(jnp.asarray(x, jnp.uint32), plan_for(params, idx), backend)
+    return ntt_ops.ntt_fwd(dispatch.upload(x, np.uint32), plan_for(params, idx), backend)
 
 
 def to_coeff(x, params: CkksParams, idx: tuple[int, ...], backend: str = "auto"):
     """Evaluation → coefficient domain over the limb subset ``idx``."""
     trace.record("INTT", params.n, len(idx))
-    return ntt_ops.ntt_inv(jnp.asarray(x, jnp.uint32), plan_for(params, idx), backend)
+    return ntt_ops.ntt_inv(dispatch.upload(x, np.uint32), plan_for(params, idx), backend)
 
 
 @functools.lru_cache(maxsize=512)
+@dispatch.spanned("table.eval_perm")
 def _eval_perm(n: int, t: int):
-    return jnp.asarray(nttmod.galois_eval_perm(n, t))
+    return dispatch.upload(nttmod.galois_eval_perm(n, t))
 
 
 def automorphism_eval(x, n: int, t: int):

@@ -57,5 +57,6 @@ def bconv_pallas(xhat, wl, c_mont, q, qinv, *, interpret):
         ],
         out_specs=pl.BlockSpec((m8, nb), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((m8, n), jnp.uint32),
+        name="bconv",
         interpret=interpret,
     )

@@ -1,42 +1,88 @@
-"""Kernel-dispatch counting — the measurable half of the fusion story.
+"""Kernel-dispatch seam: launch counts and the program's spans on the profiler clock.
 
-Every public op wrapper (ntt, bconv, modops, fusedks) records one dispatch per
-device-kernel launch it issues.  The fused key-switch pipeline's whole point is
-collapsing the staged per-digit launch train (prescale, BConv, NTT, two MACs,
-two accumulates — each a separate launch whose intermediates round-trip through
-HBM-equivalent buffers) into one `pallas_call`; this module lets benchmarks and
-tests *measure* that collapse instead of asserting it.
+Counting.  Every public op wrapper (ntt, bconv, modops, fusedks, hoistrot)
+opens ``with launch(op):`` around its body, which records one dispatch per
+device-kernel launch it issues.  The fused key-switch pipeline's whole point
+is collapsing the staged per-digit launch train (prescale, BConv, NTT, two
+MACs, two accumulates — each a separate launch whose intermediates round-trip
+through HBM-equivalent buffers) into one `pallas_call`; ``count_dispatches``
+lets benchmarks and tests *measure* that collapse instead of asserting it.
 
-Counting happens at Python call time, so inside an enclosing `jax.jit` the
-counts reflect trace-time launches (once per compilation), which is exactly
-the static dispatch count of the compiled program.
+Spans.  Each layer boundary writes a ``jax.profiler.TraceAnnotation`` with a
+constant name, so it lands in the profiler's host plane on the same clock as
+the device planes.  An annotation records only while a profiler trace runs;
+otherwise it costs a fraction of a microsecond.  Spans of one call nest by
+time on the calling thread:
 
-Tracing: ``repro.obs.Tracer.dispatch_hook()`` plugs into ``hook_dispatches``
-(or ``ExecPolicy.traced``) and turns each launch into a unit-width Perfetto
-slice at its dispatch *index* — kernels carry no simulated time, so the index
-is the deterministic clock for that track (see docs/observability.md).
+* ``fhe.<method>``: one public ``FheContext`` method, end to end on the host;
+* ``ks.<stage>``: one key-switch stage of ``repro.fhe.keyswitch``
+  (``accumulate``, ``moddown``, ``modup``, ``mac``, ``moddown_group``);
+* ``kernel.<op>``: the host side of one kernel launch (``launch``): constant
+  tiling, reshapes, uploads and the jitted kernel call;
+* ``h2d``: one host-to-device transfer of a numpy or Python value (``upload``);
+* ``table.<name>``: one build of a cached table, inside the ``lru_cache``d
+  function that builds it, so it fires only on a cache miss.
+
+Counts and spans happen at Python call time, so inside an enclosing
+`jax.jit` both fire at trace time only (once per compilation): the counts are
+then the static dispatch count of the compiled program, and the spans cover
+tracing, not execution.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.profiler import TraceAnnotation
+
+KERNEL_OPS = (
+    "mulmod", "addmod", "submod", "ntt", "intt", "bconv",
+    "fusedks", "fused_moddown", "hoistmodup", "hoistmac",
+)
+_KERNEL_SPANS = {op: f"kernel.{op}" for op in KERNEL_OPS}
 
 _COUNTS: contextvars.ContextVar[dict | None] = contextvars.ContextVar(
     "kernel_dispatch_counts", default=None
 )
-_HOOKS: contextvars.ContextVar[tuple] = contextvars.ContextVar(
-    "kernel_dispatch_hooks", default=()
-)
 
 
-def record(op: str) -> None:
-    """Count one kernel dispatch under ``op`` when a counter is active."""
+def spanned(name: str):
+    """Decorator: every call of the function runs under the host span ``name``.
+    Under ``functools.lru_cache`` it fires on cache misses only."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with TraceAnnotation(name):
+                return fn(*args, **kwargs)
+
+        return inner
+
+    return wrap
+
+
+def launch(op: str) -> TraceAnnotation:
+    """Count one dispatch of kernel ``op`` (one of ``KERNEL_OPS``) when a
+    counter is active; returns its ``kernel.<op>`` span for ``with``."""
     c = _COUNTS.get()
     if c is not None:
         c[op] = c.get(op, 0) + 1
-    for hook in _HOOKS.get():
-        hook(op)
+    return TraceAnnotation(_KERNEL_SPANS[op])
+
+
+def upload(x, dtype=None):
+    """``x`` on the default device as ``dtype`` (default: its own).  A numpy
+    or Python value is one explicit ``jax.device_put`` under an ``h2d`` span;
+    a value already on a device is only cast."""
+    if isinstance(x, jax.Array):
+        return jnp.asarray(x, dtype)
+    with TraceAnnotation("h2d"):
+        return jax.device_put(np.asarray(x, dtype))
 
 
 @contextlib.contextmanager
@@ -49,25 +95,5 @@ def count_dispatches():
         _COUNTS.reset(token)
 
 
-@contextlib.contextmanager
-def hook_dispatches(fn):
-    """Invoke ``fn(op)`` on every kernel dispatch inside the block.
-
-    Unlike ``count_dispatches`` (one aggregate dict per block), hooks compose:
-    nested blocks stack, and every active hook sees every dispatch.  This is
-    the mechanism behind ``ExecPolicy.dispatch_hook`` — an evaluation context
-    can observe its own kernel-launch stream without owning the call site.
-    """
-    token = _HOOKS.set(_HOOKS.get() + (fn,))
-    try:
-        yield
-    finally:
-        _HOOKS.reset(token)
-
-
 def total(counts: dict) -> int:
     return sum(counts.values())
-
-
-def counting() -> bool:
-    return _COUNTS.get() is not None

@@ -151,6 +151,7 @@ def fused_ks_pallas(xd, sc, dsc, wm, twa, v2, v1, t, ksk, *, interpret):
         + [pl.BlockSpec((None, 2, None, n1, n2), lambda e, j: (j, 0, e, 0, 0))],
         out_specs=pl.BlockSpec((None, 2, n1, n2), lambda e, j: (e, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 2, n1, n2), jnp.uint32),
+        name="fusedks",
         interpret=interpret,
     )
 
@@ -191,5 +192,6 @@ def fused_moddown_pallas(pc, sc, dsc, wm, pinv, twa, v2, v1, t, qpart, *, interp
         + [pl.BlockSpec((None, None, n1, n2), lambda c, e: (c, e, 0, 0))],
         out_specs=pl.BlockSpec((None, None, n1, n2), lambda c, e: (c, e, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, m, n1, n2), jnp.uint32),
+        name="fused_moddown",
         interpret=interpret,
     )

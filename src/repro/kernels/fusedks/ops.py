@@ -25,6 +25,7 @@ import functools
 
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.fhe import modmath as mm
 from repro.fhe import ntt as nttmod
@@ -79,10 +80,11 @@ def _prescale_tables(digits: list[tuple[int, ...]], dst_primes, k: int):
         dsc[j, :n, _k.DBINV] = mm.mont_constants_array(list(src))["qinv_neg"]
         dsc[j, :n, _k.DBH] = [(int(bhat_inv[i]) << 32) % int(b) for i, b in enumerate(src)]
         wm[j, :n] = (np.asarray(wj, np.uint64) << np.uint64(32)) % dst
-    return jnp.asarray(dsc.reshape(-1)), jnp.asarray(wm.reshape(-1))
+    return dispatch.upload(dsc.reshape(-1)), dispatch.upload(wm.reshape(-1))
 
 
 @functools.lru_cache(maxsize=256)
+@dispatch.spanned("table.ks")
 def ks_tables(params: CkksParams, level: int) -> KsTables:
     alpha = params.alpha
     _check_fits(alpha, params.n)
@@ -112,6 +114,7 @@ class ModDownTables:
 
 
 @functools.lru_cache(maxsize=256)
+@dispatch.spanned("table.moddown")
 def moddown_tables(params: CkksParams, level: int) -> ModDownTables:
     p_primes = poly.primes_for(params, poly.p_idx(params))
     _check_fits(len(p_primes), params.n)
@@ -121,16 +124,19 @@ def moddown_tables(params: CkksParams, level: int) -> ModDownTables:
     P = rns.product(p_primes)
     pinv = np.array([(pow(P % int(q), -1, int(q)) << 32) % int(q) for q in q_primes], np.uint32)
     return ModDownTables(
-        k=len(p_primes), m=len(q_primes), dsc=dsc, wm=wm, pinv=jnp.asarray(pinv),
+        k=len(p_primes), m=len(q_primes), dsc=dsc, wm=wm, pinv=dispatch.upload(pinv),
         ntt=ntt_ops.kernel_tables(plan, len(q_primes), inverse=False),
     )
 
 
-def pack_digits(d_coeff, tb: KsTables, n: int):
-    """(nq, N) coefficient limbs → (β, k, N2, N1) zero-padded digit blocks."""
-    xd = jnp.zeros((tb.beta, tb.k, n), jnp.uint32)
-    for j, (lo, hi) in enumerate(tb.spans):
-        xd = xd.at[j, : hi - lo].set(d_coeff[lo:hi])
+def pack_digits(d_coeff, tb: KsTables):
+    """(nq, N) coefficient limbs → (β, k, N2, N1) zero-padded digit blocks.
+
+    Digit j holds limbs [j·k, (j+1)·k) and only the last one is short, so
+    zero rows appended after the last limb complete it.  Static slices and a
+    pad with an uploaded zero keep this free of implicit index transfers."""
+    pad = tb.beta * tb.k - d_coeff.shape[0]
+    xd = lax.pad(d_coeff, dispatch.upload(0, np.uint32), [(0, pad, 0), (0, 0, 0)])
     return xd.reshape(tb.beta, tb.k, tb.ntt.n2, tb.ntt.n1)
 
 
@@ -143,16 +149,17 @@ def key_switch_digits(d_coeff, ksk_sel, params: CkksParams, level: int, backend:
     """
     if tpu.resolve(backend) == "ref":
         return _ref.key_switch_digits_ref(d_coeff, ksk_sel, params, level)
-    tb = ks_tables(params, level)
-    nt = tb.ntt
-    xd = pack_digits(jnp.asarray(d_coeff, jnp.uint32), tb, params.n)
-    ksk = jnp.asarray(ksk_sel, jnp.uint32).reshape(tb.beta, 2, tb.m, nt.n1, nt.n2)
-    dispatch.record("fusedks")
-    out = _k.fused_ks_pallas(
-        xd, nt.sc, tb.dsc, tb.wm, nt.tw, nt.v2, nt.v1, nt.t, ksk,
-        interpret=not tpu.on_tpu(),
-    ).reshape(tb.m, 2, params.n)
-    return out[:, 0], out[:, 1]
+    with dispatch.launch("fusedks"):
+        tb = ks_tables(params, level)
+        nt = tb.ntt
+        xd = pack_digits(dispatch.upload(d_coeff, np.uint32), tb)
+        ksk = dispatch.upload(ksk_sel, np.uint32).reshape(tb.beta, 2, tb.m, nt.n1, nt.n2)
+        out = _k.fused_ks_pallas(
+            xd, nt.sc, tb.dsc, tb.wm, nt.tw, nt.v2, nt.v1, nt.t, ksk,
+            interpret=not tpu.on_tpu(),
+        ).reshape(tb.m, 2, params.n)
+        return (lax.index_in_dim(out, 0, axis=1, keepdims=False),
+                lax.index_in_dim(out, 1, axis=1, keepdims=False))
 
 
 def mod_down_digits(p_coeff, q_part, params: CkksParams, level: int, backend: str = "auto"):
@@ -165,14 +172,14 @@ def mod_down_digits(p_coeff, q_part, params: CkksParams, level: int, backend: st
     """
     if tpu.resolve(backend) == "ref":
         return _ref.mod_down_digits_ref(p_coeff, q_part, params, level)
-    tb = moddown_tables(params, level)
-    nt = tb.ntt
-    nb = p_coeff.shape[0]
-    pc = jnp.asarray(p_coeff, jnp.uint32).reshape(nb, tb.k, nt.n2, nt.n1)
-    qp = jnp.asarray(q_part, jnp.uint32).reshape(nb, tb.m, nt.n1, nt.n2)
-    dispatch.record("fused_moddown")
-    out = _k.fused_moddown_pallas(
-        pc, nt.sc, tb.dsc, tb.wm, tb.pinv, nt.tw, nt.v2, nt.v1, nt.t, qp,
-        interpret=not tpu.on_tpu(),
-    )
-    return out.reshape(nb, tb.m, params.n)
+    with dispatch.launch("fused_moddown"):
+        tb = moddown_tables(params, level)
+        nt = tb.ntt
+        nb = p_coeff.shape[0]
+        pc = dispatch.upload(p_coeff, np.uint32).reshape(nb, tb.k, nt.n2, nt.n1)
+        qp = dispatch.upload(q_part, np.uint32).reshape(nb, tb.m, nt.n1, nt.n2)
+        out = _k.fused_moddown_pallas(
+            pc, nt.sc, tb.dsc, tb.wm, tb.pinv, nt.tw, nt.v2, nt.v1, nt.t, qp,
+            interpret=not tpu.on_tpu(),
+        )
+        return out.reshape(nb, tb.m, params.n)

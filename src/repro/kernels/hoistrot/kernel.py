@@ -70,6 +70,7 @@ def hoist_modup_pallas(xd, sc, dsc, wm, twa, v2, v1, t, *, interpret):
         + _ntt_specs(n1, n2, lambda e, j: e),
         out_specs=pl.BlockSpec((None, None, n1, n2), lambda e, j: (j, e, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((beta, m, n1, n2), jnp.uint32),
+        name="hoistmodup",
         interpret=interpret,
     )
 
@@ -116,5 +117,6 @@ def hoist_mac_pallas(dig, ksk, sc, *, interpret):
         ],
         out_specs=pl.BlockSpec((None, 2, None, n1, n2), lambda e, r: (r, 0, e, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nrot, 2, m, n1, n2), jnp.uint32),
+        name="hoistmac",
         interpret=interpret,
     )

@@ -17,7 +17,7 @@ weights, extended-basis NTT plan) are the same cache.
 
 from __future__ import annotations
 
-import jax.numpy as jnp
+import numpy as np
 
 from repro.fhe.params import CkksParams
 from repro.kernels import dispatch, tpu
@@ -36,14 +36,14 @@ def mod_up_digits(d_coeff, params: CkksParams, level: int, backend: str = "auto"
     """
     if tpu.resolve(backend) == "ref":
         return _ref.mod_up_digits_ref(d_coeff, params, level)
-    tb = fused_ops.ks_tables(params, level)
-    nt = tb.ntt
-    xd = fused_ops.pack_digits(jnp.asarray(d_coeff, jnp.uint32), tb, params.n)
-    dispatch.record("hoistmodup")
-    out = _k.hoist_modup_pallas(
-        xd, nt.sc, tb.dsc, tb.wm, nt.tw, nt.v2, nt.v1, nt.t, interpret=not tpu.on_tpu(),
-    )
-    return out.reshape(tb.beta, tb.m, params.n)
+    with dispatch.launch("hoistmodup"):
+        tb = fused_ops.ks_tables(params, level)
+        nt = tb.ntt
+        xd = fused_ops.pack_digits(dispatch.upload(d_coeff, np.uint32), tb)
+        out = _k.hoist_modup_pallas(
+            xd, nt.sc, tb.dsc, tb.wm, nt.tw, nt.v2, nt.v1, nt.t, interpret=not tpu.on_tpu(),
+        )
+        return out.reshape(tb.beta, tb.m, params.n)
 
 
 def galois_mac(dig, ksk, params: CkksParams, level: int, backend: str = "auto",
@@ -60,14 +60,14 @@ def galois_mac(dig, ksk, params: CkksParams, level: int, backend: str = "auto",
         return _ref.galois_mac_ref(dig, ksk, params, level, stage=backend)
     if tpu.resolve(backend) == "ref":
         return _ref.galois_mac_ref(dig, ksk, params, level)
-    tb = fused_ops.ks_tables(params, level)
-    nt = tb.ntt
-    beta, m, n = dig.shape
-    nrot = ksk.shape[0]
-    dispatch.record("hoistmac")
-    out = _k.hoist_mac_pallas(
-        jnp.asarray(dig, jnp.uint32).reshape(beta, m, nt.n1, nt.n2),
-        jnp.asarray(ksk, jnp.uint32).reshape(nrot, beta, 2, m, nt.n1, nt.n2),
-        nt.sc, interpret=not tpu.on_tpu(),
-    )
-    return out.reshape(nrot, 2, m, n)
+    with dispatch.launch("hoistmac"):
+        tb = fused_ops.ks_tables(params, level)
+        nt = tb.ntt
+        beta, m, n = dig.shape
+        nrot = ksk.shape[0]
+        out = _k.hoist_mac_pallas(
+            dispatch.upload(dig, np.uint32).reshape(beta, m, nt.n1, nt.n2),
+            dispatch.upload(ksk, np.uint32).reshape(nrot, beta, 2, m, nt.n1, nt.n2),
+            nt.sc, interpret=not tpu.on_tpu(),
+        )
+        return out.reshape(nrot, 2, m, n)

@@ -44,7 +44,7 @@ def _sub_body(q_ref, a_ref, b_ref, o_ref):
     o_ref[...] = jnp.where(a >= b, a - b, a + q - b)
 
 
-def _rowwise(body, scalars, a, b, interpret):
+def _rowwise(name, body, scalars, a, b, interpret):
     """Run ``body`` over (rows, N) operands, one limb row per program."""
     rows, n = a.shape
     tile = (n // LANES, LANES)
@@ -56,6 +56,7 @@ def _rowwise(body, scalars, a, b, interpret):
         in_specs=[tpu.smem()] * len(scalars) + [spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows,) + tile, jnp.uint32),
+        name=name,
         interpret=interpret,
     )
     return out.reshape(rows, n)
@@ -64,14 +65,14 @@ def _rowwise(body, scalars, a, b, interpret):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def mulmod_pallas(a, b, q, qinv, r2, *, interpret):
     """a, b: (rows, N) uint32; q/qinv/r2: (rows,) uint32 per-row constants."""
-    return _rowwise(_mul_body, (q, qinv, r2), a, b, interpret)
+    return _rowwise("mulmod", _mul_body, (q, qinv, r2), a, b, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def addmod_pallas(a, b, q, *, interpret):
-    return _rowwise(_add_body, (q,), a, b, interpret)
+    return _rowwise("addmod", _add_body, (q,), a, b, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def submod_pallas(a, b, q, *, interpret):
-    return _rowwise(_sub_body, (q,), a, b, interpret)
+    return _rowwise("submod", _sub_body, (q,), a, b, interpret)

@@ -45,16 +45,17 @@ def scalar_table(plan: NttPlan, l: int | None = None) -> np.ndarray:
 
 
 @functools.lru_cache(maxsize=64)
+@dispatch.spanned("table.ntt")
 def kernel_tables(plan: NttPlan, l: int, inverse: bool) -> KernelTables:
     """Device tables for the first ``l`` limbs of ``plan`` (plans hash by identity)."""
-    pick = (lambda fwd, inv: (inv if inverse else fwd)[:l])
+    pick = (lambda fwd, inv: dispatch.upload((inv if inverse else fwd)[:l]))
     return KernelTables(
         n1=plan.n1, n2=plan.n2,
-        sc=jnp.asarray(scalar_table(plan, l)),
-        tw=jnp.asarray(pick(plan.twa_mont, plan.twia_mont)),
-        v2=jnp.asarray(pick(plan.v2_limbs, plan.v2i_limbs)),
-        v1=jnp.asarray(pick(plan.v1_limbs, plan.v1i_limbs)),
-        t=jnp.asarray(pick(plan.t_mont, plan.ti_mont)),
+        sc=dispatch.upload(scalar_table(plan, l)),
+        tw=pick(plan.twa_mont, plan.twia_mont),
+        v2=pick(plan.v2_limbs, plan.v2i_limbs),
+        v1=pick(plan.v1_limbs, plan.v1i_limbs),
+        t=pick(plan.t_mont, plan.ti_mont),
     )
 
 
@@ -64,7 +65,7 @@ def _run_kernel(x, plan: NttPlan, inverse: bool):
     tb = kernel_tables(plan, l, inverse)
     # natural order either way: coefficients as (N2, N1) tiles, slots as (N1, N2)
     tile = (tb.n1, tb.n2) if inverse else (tb.n2, tb.n1)
-    xb = jnp.asarray(x, jnp.uint32).reshape((-1, l) + tile)
+    xb = dispatch.upload(x, np.uint32).reshape((-1, l) + tile)
     out = _k.ntt_pallas(
         xb, tb.sc, tb.tw, tb.v2, tb.v1, tb.t, inverse=inverse, interpret=not tpu.on_tpu(),
     )
@@ -73,15 +74,15 @@ def _run_kernel(x, plan: NttPlan, inverse: bool):
 
 def ntt_fwd(x, plan: NttPlan, backend: str = "auto"):
     """Coefficients → NTT slots (natural order).  x: (..., l, N) uint32."""
-    dispatch.record("ntt")
-    if tpu.resolve(backend) == "kernel":
-        return _run_kernel(x, plan, inverse=False)
-    return _ref.ntt_fwd_ref(x, plan)
+    with dispatch.launch("ntt"):
+        if tpu.resolve(backend) == "kernel":
+            return _run_kernel(x, plan, inverse=False)
+        return _ref.ntt_fwd_ref(x, plan)
 
 
 def ntt_inv(x, plan: NttPlan, backend: str = "auto"):
     """NTT slots → coefficients.  x: (..., l, N) uint32."""
-    dispatch.record("intt")
-    if tpu.resolve(backend) == "kernel":
-        return _run_kernel(x, plan, inverse=True)
-    return _ref.ntt_inv_ref(x, plan)
+    with dispatch.launch("intt"):
+        if tpu.resolve(backend) == "kernel":
+            return _run_kernel(x, plan, inverse=True)
+        return _ref.ntt_inv_ref(x, plan)

@@ -5,7 +5,8 @@
   interpreter (``interpret=not on_tpu()``), so CPU tests drive the same
   program.  ``resolve("auto")`` is "kernel" on a TPU and the uint64 oracle
   ("ref") elsewhere.
-* ``call`` traces ``pl.pallas_call`` with 64-bit types off.  ``repro.fhe``
+* ``call`` traces ``pl.pallas_call`` with 64-bit types off, named after the
+  wrapper's op in ``repro.kernels.dispatch.KERNEL_OPS``.  ``repro.fhe``
   enables x64 process-wide for its uint64 oracle, and under x64 the grid
   indices and index-map results become i64, which Mosaic cannot lower.  Every
   kernel here is uint32/bf16 already, so nothing else changes.
@@ -42,10 +43,11 @@ def smem() -> pl.BlockSpec:
     return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def call(kernel, args, *, grid, in_specs, out_specs, out_shape, interpret: bool):
-    """``pl.pallas_call(kernel, ...)(*args)`` traced with 32-bit index types."""
+def call(kernel, args, *, name: str, grid, in_specs, out_specs, out_shape, interpret: bool):
+    """``pl.pallas_call(kernel, ...)(*args)`` traced with 32-bit index types,
+    under the stable kernel ``name`` (the wrapper's ``dispatch.launch`` op)."""
     with jax.enable_x64(False):
         return pl.pallas_call(
             kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-            out_shape=out_shape, interpret=interpret,
+            out_shape=out_shape, interpret=interpret, name=name,
         )(*args)

@@ -1,8 +1,10 @@
 """repro.obs — observability: span tracing, Perfetto export, metrics, perf history.
 
   trace    — ``Tracer``: deterministic span/instant/counter/async events with
-             sim-clock (event loop) or dispatch-index timestamps; a no-op
-             when disabled, so every seam defaults to zero overhead
+             sim-clock (event loop) timestamps; a no-op when disabled, so
+             every seam defaults to zero overhead.  Kernel launches and HE
+             ops are traced on the profiler's clock instead
+             (``repro.kernels.dispatch``)
   export   — Chrome/Perfetto ``trace_event`` JSON: chips→processes,
              affiliations/lanes→threads; canonical byte-stable serialisation
              plus the structural validator CI uses

@@ -10,11 +10,11 @@ convention across this repo:
   * **thread (tid)**  — one per resource lane inside a chip: the chip-level
     health track, one track per cluster affiliation, the ``deep`` gang
     track (FLASH-FHE chips) or the single ``whole-chip`` track (sequential
-    baselines).  Simulator/dispatch traces intern tracks per functional
-    unit the same way.
+    baselines).  Simulator traces intern tracks per functional unit the
+    same way.
   * **ts/dur**        — simulated *cycles*, not microseconds.  Perfetto
     renders them as µs; read "1 µs" as "1 cycle".  Timestamps are sim-clock
-    or dispatch-index values, so same-seed runs export byte-identical files.
+    values, so same-seed runs export byte-identical files.
 
 Serialisation is canonical — events stably sorted by (ts, emission order)
 with metadata first, ``json.dumps(sort_keys=True, separators=(",", ":"))``

@@ -6,8 +6,8 @@ three hard rules that make traces *reproducible artifacts* rather than
 profiler noise:
 
 * **Sim-clock timestamps only.**  Every timestamp comes from the bound clock
-  (the serving ``EventLoop``'s cycle counter), an explicit ``ts=`` argument,
-  or a dispatch index — never from wall-clock time.  Two runs with the same
+  (the serving ``EventLoop``'s cycle counter) or an explicit ``ts=``
+  argument — never from wall-clock time.  Two runs with the same
   seed therefore export byte-identical traces, and a trace diff is a
   behaviour diff.
 * **Zero overhead when disabled.**  ``Tracer(enabled=False)`` (and the
@@ -58,7 +58,6 @@ class Tracer:
         # (pid, label) -> tid, interned in registration order per pid
         self._tracks: dict[tuple[int, str], int] = {}
         self._next_tid: dict[int, int] = {}
-        self.n_dispatches = 0  # dispatch-index clock for kernel-launch events
 
     def __bool__(self) -> bool:
         return self.enabled
@@ -195,22 +194,3 @@ class Tracer:
                 ts: float | None = None, pid: int = 0, **args) -> None:
         self.async_end(name, job_id, cat="job", ts=ts, pid=pid,
                        state=state, **args)
-
-    # -- kernel-dispatch seam -------------------------------------------------
-
-    def dispatch_hook(self, pid: int = 0, label: str = "kernel-dispatch"):
-        """A hook for ``kernels.dispatch.hook_dispatches`` (or
-        ``ExecPolicy(dispatch_hook=...)``, via ``ExecPolicy.traced``): each
-        kernel launch becomes a unit-width "X" slice at its *dispatch index*
-        — kernels carry no sim-time of their own, so the index is the
-        deterministic clock for this track."""
-        tid = self.track(pid, label)
-
-        def hook(op: str) -> None:
-            if self.enabled:
-                i = self.n_dispatches
-                self.n_dispatches = i + 1
-                self.events.append({"ph": "X", "name": op, "ts": float(i),
-                                    "dur": 1.0, "pid": pid, "tid": tid,
-                                    "args": {}})
-        return hook

@@ -8,8 +8,8 @@ Three contracts pinned here:
     never silently delegating; the context methods carry the full numerics
     contract (cross-backend bit-exactness, hypothesis-driven);
   * **policy identity** — ``ExecPolicy.policy_key()`` distinguishes every
-    (scheme, backend, hoisting, numerics) combination, excludes the dispatch
-    hook, and is what keys the serving service-time memo (no mode aliasing);
+    (scheme, backend, hoisting, numerics) combination and is what keys the
+    serving service-time memo (no mode aliasing);
   * **planning** — ``plan_matrix``/``choose_n1`` pick the baby-step count
     from the hoisting-aware cost model (n1 = 16 for the radix-32 CtS stage
     shape the hoisting bench measures, vs the classic √n without hoisting).
@@ -29,7 +29,6 @@ from repro.fhe import keys as K
 from repro.fhe import linear, ops, polyeval
 from repro.fhe import params as P
 from repro.fhe.context import BACKENDS, HOISTING_MODES, NUMERICS_MODES, ExecPolicy, FheContext
-from repro.kernels import dispatch
 from repro.serve import policy as SP
 
 ROTS = (1, 2, 3, 4, 5)
@@ -188,13 +187,6 @@ def test_policy_key_distinguishes_every_combination():
     assert len(keys) == len(combos), "policy_key aliases distinct policies"
 
 
-def test_policy_key_excludes_dispatch_hook():
-    a = ExecPolicy(backend="ref")
-    b = ExecPolicy(backend="ref", dispatch_hook=lambda op: None)
-    assert a.policy_key() == b.policy_key()
-    assert a == b  # observation must not change equality either
-
-
 def test_policy_validation():
     with pytest.raises(ValueError):
         ExecPolicy(backend="vectorized")
@@ -259,19 +251,6 @@ def test_with_policy_scoped_override(cset):
     assert replaced.policy.backend == "ref"
     with pytest.raises(TypeError):
         ctx.with_policy(policy=ExecPolicy(), backend="ref")
-
-
-def test_dispatch_hook_observes_kernel_launches(cset):
-    _, _, ctx, ct_a, ct_b, _, _ = cset
-    seen: list[str] = []
-    hooked = ctx.with_policy(backend="ref", dispatch_hook=seen.append)
-    hooked.add(ct_a, ct_b)
-    assert seen == ["addmod", "addmod"]  # c0 and c1
-    # hooks compose with an enclosing counter instead of replacing it
-    seen.clear()
-    with dispatch.count_dispatches() as counts:
-        hooked.mul(ct_a, ct_b)
-    assert counts and sum(counts.values()) == len(seen)
 
 
 def test_keyless_context_rejects_key_ops(cset):

@@ -18,7 +18,6 @@ from repro.core import jobs as J
 from repro.core import planner as PL
 from repro.core.simulator import lanes_whole_chip, simulate_stream
 from repro.fhe import params as P
-from repro.fhe.context import ExecPolicy
 from repro.obs import (
     MetricsRegistry,
     Tracer,
@@ -83,10 +82,8 @@ def test_disabled_tracer_records_nothing():
     tr.job_end(0, "matmul", "DONE")
     with tr.span("nested"):
         pass
-    tr.dispatch_hook()("NTT")
     assert tr.events == []
     assert tr.process_names == {}
-    assert tr.n_dispatches == 0
 
 
 def test_track_ids_interned_per_registration_order():
@@ -116,17 +113,6 @@ def test_bound_clock_is_default_timestamp_source():
     tr.instant("b")
     tr.instant("c", ts=7.0)                   # explicit ts wins — but note it
     assert [e["ts"] for e in tr.events] == [0.0, 42.0, 7.0]
-
-
-def test_dispatch_hook_uses_dispatch_index_clock():
-    tr = Tracer()
-    hook = tr.dispatch_hook(pid=5)
-    for op in ("NTT", "BCONV", "NTT"):
-        hook(op)
-    assert tr.n_dispatches == 3
-    assert [(e["name"], e["ts"], e["dur"]) for e in tr.events] == [
-        ("NTT", 0.0, 1.0), ("BCONV", 1.0, 1.0), ("NTT", 2.0, 1.0)]
-    assert validate_chrome_trace(to_chrome_trace(tr)) == []
 
 
 # ---------------------------------------------------------------------------
@@ -183,26 +169,6 @@ def test_validator_catches_structural_problems():
     # separate tracks are independent clocks
     split = {"traceEvents": [_inst("late", 10.0, 0), _inst("early", 5.0, 1)]}
     assert validate_chrome_trace(split) == []
-
-
-# ---------------------------------------------------------------------------
-# seam: kernel dispatch via ExecPolicy.traced
-# ---------------------------------------------------------------------------
-
-
-def test_exec_policy_traced_composes_and_preserves_identity():
-    seen = []
-    base = ExecPolicy(dispatch_hook=seen.append)
-    tr = Tracer()
-    traced = base.traced(tr)
-    assert traced.policy_key() == base.policy_key()   # hooks excluded from identity
-    traced.dispatch_hook("NTT")
-    traced.dispatch_hook("BCONV")
-    assert seen == ["NTT", "BCONV"]                   # prior hook still fires
-    assert [e["name"] for e in tr.events] == ["NTT", "BCONV"]
-    # None / disabled tracer: the policy is returned unchanged
-    assert base.traced(None) is base
-    assert base.traced(Tracer(enabled=False)) is base
 
 
 # ---------------------------------------------------------------------------

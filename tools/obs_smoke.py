@@ -6,7 +6,7 @@ window, transient job failures, retries, and admission — then checks the
 four properties CI gates on:
 
   1. **determinism** — two runs with the same seed export byte-identical
-     Chrome trace JSON (the tracer records only sim-clock/index timestamps);
+     Chrome trace JSON (the tracer records only sim-clock timestamps);
   2. **structural validity** — ``validate_chrome_trace`` finds balanced B/E
      stacks, balanced async spans, monotone per-track timestamps, and only
      known phases;

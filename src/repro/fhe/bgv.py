@@ -38,9 +38,12 @@ expansions (``bgv_hmul``, ``bgv_mod_switch``) for the serving simulator.
 from __future__ import annotations
 
 import dataclasses
+import functools
+from typing import NamedTuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.kernels import dispatch
 from repro.kernels.modops import ops as mo
@@ -287,6 +290,34 @@ def _mul(ctx, a: BgvCiphertext, b: BgvCiphertext, rlk: SwitchingKey,
 # ---------------------------------------------------------------------------
 
 
+class _ModSwitchTables(NamedTuple):
+    """Device constants of a modulus switch from level ``lv`` (remaining limbs i < lv)."""
+
+    qinv: jnp.ndarray  # (lv, N) uint32: q_ℓ⁻¹ mod q_i along limb i, the mulmod operand
+    qs_col: jnp.ndarray  # (lv, 1) int64 remaining moduli
+    q_last: jnp.ndarray  # () uint64 q_ℓ
+    q_last_i: jnp.ndarray  # () int64 q_ℓ
+    half: jnp.ndarray  # () uint64 ⌊q_ℓ/2⌋
+    tinv: jnp.ndarray  # () uint64 t⁻¹ mod q_ℓ
+    t: jnp.ndarray  # () int64 t
+
+
+@functools.lru_cache(maxsize=256)
+@dispatch.spanned("table.modswitch")
+def _mod_switch_tables(params: CkksParams, lv: int, device) -> _ModSwitchTables:
+    """Built once per (params, level) and default ``device``, like ``mo.limb_constants``."""
+    t = _t(params)
+    q_last = int(params.q_primes[lv])
+    qinv = np.array([pow(q_last % int(q), -1, int(q)) for q in params.q_primes[:lv]], np.uint32)
+    up = dispatch.upload
+    return _ModSwitchTables(
+        qinv=up(np.broadcast_to(qinv[:, None], (lv, params.n))),
+        qs_col=up(_qs(params, lv - 1)[:, None], np.int64),
+        q_last=up(q_last, np.uint64), q_last_i=up(q_last, np.int64),
+        half=up(q_last // 2, np.uint64), tinv=up(pow(t, -1, q_last), np.uint64), t=up(t, np.int64),
+    )
+
+
 def _mod_switch(ctx, ct: BgvCiphertext) -> BgvCiphertext:
     """Drop q_ℓ: c' = (c − δ)·q_ℓ^{-1} with δ = t·[t^{-1}·c]_{q_ℓ} centred.
 
@@ -296,32 +327,27 @@ def _mod_switch(ctx, ct: BgvCiphertext) -> BgvCiphertext:
     shape, plus one single-limb PMULT for the t^{-1} twist).
     """
     params = ctx.params
-    t = _t(params)
     lv = ct.level
     assert lv >= 1, "cannot mod-switch at level 0"
-    q_last = int(params.q_primes[lv])
     qs_rem = _qs(params, lv - 1)
-    rem_primes = params.q_primes[:lv]
     bk = ctx.stage
-    tinv = pow(t, -1, q_last)
-    qinv = np.array([pow(q_last % int(q), -1, int(q)) for q in rem_primes], np.uint64)
-    qinv_b = dispatch.upload(qinv[:, None], np.uint32)
-    qs_rem_i64 = dispatch.upload(qs_rem[:, None], np.int64)
+    tb = _mod_switch_tables(params, lv, dispatch.default_device())
 
     def _one(c):
         # iNTT the dropped limb, twist by t^{-1}, centre, re-scale by t — the
         # centred multiple-of-t congruent to c mod q_ℓ — then re-embed in the
         # remaining bases, subtract, and divide by q_ℓ.
-        last_coeff = poly.to_coeff(c[lv : lv + 1], params, (lv,), bk)
+        last_coeff = poly.to_coeff(poly.limbs(c, lv, lv + 1), params, (lv,), bk)
         trace.record("PMULT", params.n, 1)
-        u = (last_coeff[0].astype(jnp.uint64) * tinv) % q_last
-        u_signed = jnp.where(u > q_last // 2, u.astype(jnp.int64) - q_last, u.astype(jnp.int64))
-        delta = t * u_signed  # |δ| ≤ t·q_ℓ/2 < 2^47: exact in int64
-        rem = (delta[None, :] % qs_rem_i64).astype(jnp.uint32)
+        v = lax.index_in_dim(last_coeff, 0, keepdims=False).astype(jnp.uint64)
+        u = (v * tb.tinv) % tb.q_last
+        u_signed = jnp.where(u > tb.half, u.astype(jnp.int64) - tb.q_last_i, u.astype(jnp.int64))
+        delta = tb.t * u_signed  # |δ| ≤ t·q_ℓ/2 < 2^47: exact in int64
+        rem = (delta[None, :] % tb.qs_col).astype(jnp.uint32)
         rem_eval = poly.to_eval(rem, params, poly.q_idx(params, lv - 1), bk)
         trace.record("PSUB", params.n, lv)
-        diff = mo.pointwise_submod(c[:lv], rem_eval, qs_rem, backend=bk)
+        diff = mo.pointwise_submod(poly.limbs(c, 0, lv), rem_eval, qs_rem, backend=bk)
         trace.record("PMULT", params.n, lv)
-        return mo.pointwise_mulmod(diff, jnp.broadcast_to(qinv_b, diff.shape), qs_rem, backend=bk)
+        return mo.pointwise_mulmod(diff, tb.qinv, qs_rem, backend=bk)
 
     return BgvCiphertext(c0=_one(ct.c0), c1=_one(ct.c1), level=lv - 1)

@@ -17,6 +17,8 @@ plan step 3, docs/context_api.md): the old names resolve to a module
 from __future__ import annotations
 
 import dataclasses
+import functools
+from typing import NamedTuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -266,6 +268,29 @@ def _align_mul(params: CkksParams, a: Ciphertext, b: Ciphertext):
     return level_drop(a, lv), level_drop(b, lv)
 
 
+class _RescaleTables(NamedTuple):
+    """Device constants of a rescale from level ``lv`` (remaining limbs i < lv)."""
+
+    qinv: jnp.ndarray  # (lv, N) uint32: q_ℓ⁻¹ mod q_i along limb i, the mulmod operand
+    qs_col: jnp.ndarray  # (lv, 1) uint64 remaining moduli
+    q_last: jnp.ndarray  # () uint64 q_ℓ
+    half: jnp.ndarray  # () uint64 ⌊q_ℓ/2⌋
+
+
+@functools.lru_cache(maxsize=256)
+@dispatch.spanned("table.rescale")
+def _rescale_tables(params: CkksParams, lv: int, device) -> _RescaleTables:
+    """Built once per (params, level) and default ``device``, like ``mo.limb_constants``."""
+    q_last = int(params.q_primes[lv])
+    qinv = np.array([pow(q_last % int(q), -1, int(q)) for q in params.q_primes[:lv]], np.uint32)
+    return _RescaleTables(
+        qinv=dispatch.upload(np.broadcast_to(qinv[:, None], (lv, params.n))),
+        qs_col=dispatch.upload(_qs(params, lv - 1)[:, None]),
+        q_last=dispatch.upload(q_last, np.uint64),
+        half=dispatch.upload(q_last // 2, np.uint64),
+    )
+
+
 def _rescale(ctx, ct: Ciphertext) -> Ciphertext:
     """Divide by q_ℓ and drop a level (eval-domain RNS rescale)."""
     params = ctx.params
@@ -273,26 +298,21 @@ def _rescale(ctx, ct: Ciphertext) -> Ciphertext:
     assert lv >= 1, "cannot rescale at level 0"
     q_last = int(params.q_primes[lv])
     qs_rem = _qs(params, lv - 1)
-    rem_primes = params.q_primes[:lv]
     bk = ctx.stage
-    qinv = np.array([pow(q_last % int(q), -1, int(q)) for q in rem_primes], np.uint64)
-    qinv_b = dispatch.upload(qinv[:, None], np.uint32)
-    qs_col = dispatch.upload(qs_rem[:, None])
-    q_dev = dispatch.upload(q_last, np.uint64)
-    half = dispatch.upload(q_last // 2, np.uint64)
+    tb = _rescale_tables(params, lv, dispatch.default_device())
 
     def _one(c):
         # iNTT the dropped limb, re-embed its (centred) coefficients in every
         # remaining basis, NTT back, subtract, multiply by q_ℓ^{-1}.
         last_coeff = poly.to_coeff(poly.limbs(c, lv, lv + 1), params, (lv,), bk)
         v = lax.index_in_dim(last_coeff, 0, keepdims=False).astype(jnp.uint64)
-        centered = jnp.where(v > half, v + qs_col - q_dev, v)
-        rem = (centered % qs_col).astype(jnp.uint32)
+        centered = jnp.where(v > tb.half, v + tb.qs_col - tb.q_last, v)
+        rem = (centered % tb.qs_col).astype(jnp.uint32)
         rem_eval = poly.to_eval(rem, params, poly.q_idx(params, lv - 1), bk)
         trace.record("PSUB", params.n, lv)
         diff = mo.pointwise_submod(poly.limbs(c, 0, lv), rem_eval, qs_rem, backend=bk)
         trace.record("PMULT", params.n, lv)
-        return mo.pointwise_mulmod(diff, jnp.broadcast_to(qinv_b, diff.shape), qs_rem, backend=bk)
+        return mo.pointwise_mulmod(diff, tb.qinv, qs_rem, backend=bk)
 
     return Ciphertext(c0=_one(ct.c0), c1=_one(ct.c1), level=lv - 1, scale=ct.scale / q_last)
 

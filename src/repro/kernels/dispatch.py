@@ -17,8 +17,9 @@ time on the calling thread:
 * ``fhe.<method>``: one public ``FheContext`` method, end to end on the host;
 * ``ks.<stage>``: one key-switch stage of ``repro.fhe.keyswitch``
   (``accumulate``, ``moddown``, ``modup``, ``mac``, ``moddown_group``);
-* ``kernel.<op>``: the host side of one kernel launch (``launch``): constant
-  tiling, reshapes, uploads and the jitted kernel call;
+* ``kernel.<op>``: the host side of one kernel launch (``launch``): table
+  lookups, reshapes, uploads of operands still on the host, and the jitted
+  kernel call;
 * ``h2d``: one host-to-device transfer of a numpy or Python value (``upload``);
 * ``table.<name>``: one build of a cached table, inside the ``lru_cache``d
   function that builds it, so it fires only on a cache miss.
@@ -77,12 +78,21 @@ def launch(op: str) -> TraceAnnotation:
 
 def upload(x, dtype=None):
     """``x`` on the default device as ``dtype`` (default: its own).  A numpy
-    or Python value is one explicit ``jax.device_put`` under an ``h2d`` span;
-    a value already on a device is only cast."""
+    or Python value is one explicit ``jax.device_put`` under an ``h2d`` span,
+    made at once even inside a ``jax.jit`` trace, so a cached table built
+    there holds a device array and not a tracer; a value already on a device
+    is only cast."""
     if isinstance(x, jax.Array):
         return jnp.asarray(x, dtype)
-    with TraceAnnotation("h2d"):
+    with TraceAnnotation("h2d"), jax.ensure_compile_time_eval():
         return jax.device_put(np.asarray(x, dtype))
+
+
+def default_device():
+    """The device set by an enclosing ``jax.default_device`` (None outside one):
+    part of the key of a cache of device arrays, so a table built under a
+    host-CPU oracle block is not handed to the accelerator's kernels."""
+    return jax.config.jax_default_device
 
 
 @contextlib.contextmanager

@@ -63,6 +63,7 @@ class KsTables:
     dsc: jnp.ndarray  # (β·k·NDSC,) prescale constants, layout ``kernel.DB..DBH``
     wm: jnp.ndarray  # (β·k·m,) Montgomery BConv weights [B̂_i·R]_{c_e}
     ntt: ntt_ops.KernelTables  # forward NTT over the destination basis
+    zero: jnp.ndarray  # () uint32 pad value of the digit blocks (``pack_digits``)
 
 
 def _prescale_tables(digits: list[tuple[int, ...]], dst_primes, k: int):
@@ -100,6 +101,7 @@ def ks_tables(params: CkksParams, level: int) -> KsTables:
     return KsTables(
         beta=beta, k=alpha, m=len(ext), spans=tuple(spans), dsc=dsc, wm=wm,
         ntt=ntt_ops.kernel_tables(plan, len(ext), inverse=False),
+        zero=dispatch.upload(0, np.uint32),
     )
 
 
@@ -133,10 +135,10 @@ def pack_digits(d_coeff, tb: KsTables):
     """(nq, N) coefficient limbs → (β, k, N2, N1) zero-padded digit blocks.
 
     Digit j holds limbs [j·k, (j+1)·k) and only the last one is short, so
-    zero rows appended after the last limb complete it.  Static slices and a
-    pad with an uploaded zero keep this free of implicit index transfers."""
+    zero rows appended after the last limb complete it.  A pad with the
+    tables' device-resident zero transfers nothing to the device."""
     pad = tb.beta * tb.k - d_coeff.shape[0]
-    xd = lax.pad(d_coeff, dispatch.upload(0, np.uint32), [(0, pad, 0), (0, 0, 0)])
+    xd = lax.pad(d_coeff, tb.zero, [(0, pad, 0), (0, 0, 0)])
     return xd.reshape(tb.beta, tb.k, tb.ntt.n2, tb.ntt.n1)
 
 

@@ -3,8 +3,9 @@
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
-import jax.numpy as jnp
+import jax
 import numpy as np
 
 from repro.fhe import modmath as mm
@@ -14,46 +15,48 @@ from . import kernel as _k
 from . import ref as _ref
 
 
+class LimbConstants(NamedTuple):
+    """Per-limb constants of one limb set, each (l,) uint32 on the device."""
+
+    q: jax.Array  # moduli
+    qinv_neg: jax.Array  # -q⁻¹ mod 2^32
+    r2: jax.Array  # R² mod q, R = 2^32
+
+
 @functools.lru_cache(maxsize=1024)
-@dispatch.spanned("table.mont")
-def _mont_cached(qs: tuple[int, ...]) -> dict:
-    return mm.mont_constants_array(list(qs))
+@dispatch.spanned("table.limbs")
+def _limb_tables(qs: tuple[int, ...], device) -> LimbConstants:
+    """Built once per limb set and default ``device`` (the key places the
+    arrays: ``upload`` puts them on the default device of the building call)."""
+    consts = mm.mont_constants_array(list(qs))
+    return LimbConstants(*(dispatch.upload(consts[k]) for k in LimbConstants._fields))
 
 
-def _launch(kernel, a, b, consts):
-    """Run a row kernel over (..., l, N) operands: one row per (batch, limb),
-    each per-limb constant tiled over the flattened leading batch."""
-    l, n = a.shape[-2:]
-    reps = a.size // (l * n)
-    rows = [jnp.tile(dispatch.upload(c, np.uint32).reshape(-1), reps) for c in consts]
-    out = kernel(a.reshape(-1, n), b.reshape(-1, n), *rows, interpret=not tpu.on_tpu())
-    return out.reshape(a.shape)
+def limb_constants(qs) -> LimbConstants:
+    """The device-resident constants of the limb set ``qs`` ((l,) primes)."""
+    return _limb_tables(tuple(np.asarray(qs).tolist()), dispatch.default_device())
 
 
-def pointwise_mulmod(a, b, qs, qinv=None, r2=None, backend: str = "auto"):
-    """(a ∘ b) mod q per limb.  a, b: (..., l, N) uint32; qs: (l,).
-
-    Montgomery constants are derived (and cached) from ``qs`` when the caller
-    does not supply them, so any call site can reach the kernel path.
-    """
+def pointwise_mulmod(a, b, qs, backend: str = "auto"):
+    """(a ∘ b) mod q per limb.  a, b: (..., l, N) uint32; qs: (l,) primes."""
     with dispatch.launch("mulmod"):
+        c = limb_constants(qs)
         if tpu.resolve(backend) == "ref":
-            return _ref.mulmod_ref(a, b, dispatch.upload(qs, np.uint32))
-        if qinv is None or r2 is None:
-            consts = _mont_cached(tuple(int(q) for q in np.asarray(qs).tolist()))
-            qinv, r2 = consts["qinv_neg"], consts["r2"]
-        return _launch(_k.mulmod_pallas, a, b, (qs, qinv, r2))
+            return _ref.mulmod_ref(a, b, c.q)
+        return _k.mulmod_pallas(a, b, c.q, c.qinv_neg, c.r2, interpret=not tpu.on_tpu())
 
 
 def pointwise_addmod(a, b, qs, backend: str = "auto"):
     with dispatch.launch("addmod"):
+        c = limb_constants(qs)
         if tpu.resolve(backend) == "ref":
-            return _ref.addmod_ref(a, b, dispatch.upload(qs, np.uint32))
-        return _launch(_k.addmod_pallas, a, b, (qs,))
+            return _ref.addmod_ref(a, b, c.q)
+        return _k.addmod_pallas(a, b, c.q, interpret=not tpu.on_tpu())
 
 
 def pointwise_submod(a, b, qs, backend: str = "auto"):
     with dispatch.launch("submod"):
+        c = limb_constants(qs)
         if tpu.resolve(backend) == "ref":
-            return _ref.submod_ref(a, b, dispatch.upload(qs, np.uint32))
-        return _launch(_k.submod_pallas, a, b, (qs,))
+            return _ref.submod_ref(a, b, c.q)
+        return _k.submod_pallas(a, b, c.q, interpret=not tpu.on_tpu())

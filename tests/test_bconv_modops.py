@@ -36,13 +36,10 @@ def test_pointwise_ops_kernel_matches_ref(shape):
     rng = np.random.default_rng(42)
     l = shape[-2]
     qs = np.array(PRIMES[:l], np.uint32)
-    consts = mm.mont_constants_array(qs.tolist())
     a = (rng.integers(0, 1 << 31, size=shape + (0,)[:0]).astype(np.uint64) % qs.reshape((1,) * (len(shape) - 2) + (l, 1))).astype(np.uint32)
     b = (rng.integers(0, 1 << 31, size=shape).astype(np.uint64) % qs.reshape((1,) * (len(shape) - 2) + (l, 1))).astype(np.uint32)
     a = a.reshape(shape)
-    mk = modops.pointwise_mulmod(
-        jnp.asarray(a), jnp.asarray(b), qs, consts["qinv_neg"], consts["r2"], backend="kernel"
-    )
+    mk = modops.pointwise_mulmod(jnp.asarray(a), jnp.asarray(b), qs, backend="kernel")
     mr = modops.pointwise_mulmod(jnp.asarray(a), jnp.asarray(b), qs, backend="ref")
     np.testing.assert_array_equal(np.asarray(mk), np.asarray(mr))
     ak = modops.pointwise_addmod(jnp.asarray(a), jnp.asarray(b), qs, backend="kernel")
@@ -51,6 +48,46 @@ def test_pointwise_ops_kernel_matches_ref(shape):
     sk = modops.pointwise_submod(jnp.asarray(a), jnp.asarray(b), qs, backend="kernel")
     sr = modops.pointwise_submod(jnp.asarray(a), jnp.asarray(b), qs, backend="ref")
     np.testing.assert_array_equal(np.asarray(sk), np.asarray(sr))
+
+
+POINTWISE = {
+    "mulmod": (modops.pointwise_mulmod, lambda a, b, q: a * b % q),
+    "addmod": (modops.pointwise_addmod, lambda a, b, q: (a + b) % q),
+    "submod": (modops.pointwise_submod, lambda a, b, q: (a + q - b) % q),
+}
+
+
+@pytest.mark.parametrize("op", list(POINTWISE))
+@pytest.mark.parametrize("l", [1, 5])
+@pytest.mark.parametrize("lead", [(), (2,), (2, 2)])
+def test_pointwise_kernel_indexes_constants_by_limb(op, l, lead):
+    """Each (batch row, limb) program reads its own limb's constants: the
+    kernel matches the oracle and exact host arithmetic on every leading batch."""
+    fn, exact = POINTWISE[op]
+    rng = np.random.default_rng([l, len(lead)])
+    qs = np.array(PRIMES[6 : 6 + l], np.uint64)  # both prime widths once l > 2
+    q = qs[:, None]
+    a, b = (rng.integers(0, 1 << 62, size=lead + (l, 256), dtype=np.uint64) % q for _ in range(2))
+    got = fn(jnp.asarray(a.astype(np.uint32)), jnp.asarray(b.astype(np.uint32)), qs, backend="kernel")
+    want = fn(jnp.asarray(a.astype(np.uint32)), jnp.asarray(b.astype(np.uint32)), qs, backend="ref")
+    assert got.shape == lead + (l, 256)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got), exact(a, b, q).astype(np.uint32))
+
+
+def test_limb_constants_built_inside_jit_stay_device_arrays():
+    """A limb set first met while tracing an enclosing ``jax.jit`` caches
+    device arrays, which later eager calls use as they are."""
+    import jax
+
+    qs = np.array(PRIMES[9:12], np.uint64)
+    modops._limb_tables.cache_clear()
+    a = jnp.asarray(np.arange(3 * 256, dtype=np.uint32).reshape(3, 256))
+    traced = jax.jit(lambda x: modops.pointwise_mulmod(x, x, qs, backend="ref"))(a)
+    assert all(isinstance(c, jax.Array) and not isinstance(c, jax.core.Tracer)
+               for c in modops.limb_constants(qs))
+    eager = modops.pointwise_mulmod(a, a, qs, backend="ref")
+    np.testing.assert_array_equal(np.asarray(traced), np.asarray(eager))
 
 
 @settings(max_examples=25, deadline=None)

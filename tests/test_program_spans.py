@@ -13,6 +13,7 @@ import pytest
 
 from repro.fhe import keys as K
 from repro.fhe import ntt as nttmod
+from repro.fhe import ops as fhe_ops
 from repro.fhe import params as P
 from repro.fhe.context import ExecPolicy, FheContext
 from repro.kernels import dispatch
@@ -55,6 +56,8 @@ def spans(monkeypatch):
 def test_mul_spans_nest_by_layer(kctx, spans):
     ctx, a, b = kctx
     ctx.mul(a, b)
+    spans.clear()
+    ctx.mul(a, b)  # warm: every table is built
     assert spans[0] == ("fhe.mul", ())
     assert all(outer[:1] == ("fhe.mul",) for _, outer in spans[1:])
     assert all(name.startswith(PROGRAM_PREFIXES) for name, _ in spans)
@@ -63,7 +66,8 @@ def test_mul_spans_nest_by_layer(kctx, spans):
     assert ("ks.moddown", "fhe.mul") in nest
     assert ("kernel.fusedks", "ks.accumulate") in nest
     assert ("kernel.fused_moddown", "ks.moddown") in nest
-    assert ("h2d", "kernel.mulmod") in nest
+    assert not any(name == "h2d" and any(o.startswith("kernel.") for o in outer)
+                   for name, outer in spans)
 
 
 def test_kernel_spans_match_dispatch_counts(kctx, spans):
@@ -77,12 +81,12 @@ def test_kernel_spans_match_dispatch_counts(kctx, spans):
 
 def test_table_spans_fire_on_cache_misses_only(kctx, spans):
     ctx, a, b = kctx
-    for cached in (fops.ks_tables, fops.moddown_tables, ntt_ops.kernel_tables, mo._mont_cached,
-                   nttmod.subplan):
+    for cached in (fops.ks_tables, fops.moddown_tables, ntt_ops.kernel_tables, mo._limb_tables,
+                   fhe_ops._rescale_tables, nttmod.subplan):
         cached.cache_clear()
     ctx.mul(a, b)
     built = {n for n, _ in spans if n.startswith("table.")}
-    assert {"table.ks", "table.moddown", "table.ntt", "table.mont"} <= built
+    assert {"table.ks", "table.moddown", "table.ntt", "table.limbs", "table.rescale"} <= built
     spans.clear()
     ctx.mul(a, b)
     assert [n for n, _ in spans if n.startswith("table.")] == []
@@ -111,11 +115,26 @@ def _op(ctx, a, b, name):
         return lambda: ctx.mul_plain(a, pt, rescale_after=False)
     return {
         "mul": lambda: ctx.mul(a, b),
+        "add": lambda: ctx.add(a, b),
         "mul_const_exact": lambda: ctx.mul_const_exact(a, 0.5, ctx.params.scale),
         "add_const": lambda: ctx.add_const(a, 0.5),
         "sub": lambda: ctx.sub(a, b),
         "force_to": lambda: ctx.force_to(a, a.level - 1, a.scale),
     }[name]
+
+
+@pytest.mark.parametrize("op", ["mul", "mul_plain", "add", "sub", "rescale"])
+def test_warm_ops_upload_nothing_and_build_no_table(kctx, spans, op):
+    """Per-limb constants stay on the device: a cold call uploads only while it
+    builds a table, and a warm call neither uploads nor builds."""
+    ctx, a, b = kctx
+    run = _op(ctx, a, b, op)
+    spans.clear()
+    run()
+    assert all(any(o.startswith("table.") for o in outer) for name, outer in spans if name == "h2d")
+    spans.clear()
+    run()
+    assert [n for n, _ in spans if n == "h2d" or n.startswith("table.")] == []
 
 
 @pytest.mark.parametrize("op", ["mul", "rescale", "mul_const_exact", "mul_plain", "add_const", "sub",

@@ -70,10 +70,11 @@ def _bconv(p):
 
 
 def _modops(p):
-    rows = 2 * (p.L + 1)
-    a = _u32(rows, p.n)
-    return [(modops_k.mulmod_pallas, (a, a, _u32(rows), _u32(rows), _u32(rows)), {}),
-            (modops_k.submod_pallas, (a, a, _u32(rows)), {})]
+    l = p.L + 1
+    a = _u32(2, l, p.n)  # a ciphertext's two components over the top-level limbs
+    return [(modops_k.mulmod_pallas, (a, a, _u32(l), _u32(l), _u32(l)), {}),
+            (modops_k.addmod_pallas, (a, a, _u32(l)), {}),
+            (modops_k.submod_pallas, (a, a, _u32(l)), {})]
 
 
 def _fused_ks(p):

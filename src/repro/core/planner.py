@@ -9,7 +9,8 @@ instruction stream drives both the numerics and the cycle simulator.
 
 Two modes:
   * mode="exec" mirrors repro.fhe exactly (incl. on-the-fly plaintext encodes
-    and the full Chebyshev basis) — used for validation;
+    outside BSGS, whose diagonals stay resident, and the full Chebyshev
+    basis) — used for validation;
   * mode="hw" is what the accelerator would run: plaintexts are precomputed
     (LOAD_PT), EvalMod uses the Paterson–Stockmeyer mult count (~2√d), and
     CtS/StC matvec pairs share baby rotations (the paper's cache-hit-ratio
@@ -253,6 +254,8 @@ def bsgs_matvec(
     mode: str = "exec", share_babies: bool = False, hoist: bool = False,
     fused: bool = True,
 ) -> list[Instr]:
+    """BSGS over ``n_diags`` diagonals; in both modes each diagonal is a
+    resident plaintext (LOAD_PT), as the executor caches their encodings."""
     n, nq = pp.n, level + 1
     babies = sorted({d % n1 for d in range(n_diags)} - {0})
     giants = sorted({d // n1 for d in range(n_diags)} - {0})
@@ -263,9 +266,8 @@ def bsgs_matvec(
     elif not share_babies:
         for _ in babies:
             out += rotate(pp, level, fused)
-    for d in range(n_diags):
-        out += [I("NTT", n, nq)] if mode == "exec" else [I("LOAD_PT", n, nq)]
-        out += [I("PMULT", n, 2 * nq)]
+    for d in range(n_diags):  # the executor keeps the encoded diagonals resident too
+        out += [I("LOAD_PT", n, nq), I("PMULT", n, 2 * nq)]
     # adds inside giant groups: one per diagonal beyond the first of its group
     n_groups = len(giants) + 1
     out += [I("PADD", n, 2 * nq)] * (n_diags - n_groups)

@@ -145,6 +145,11 @@ class FheContext:
     params: CkksParams
     keys: KeySet | None = None
     policy: ExecPolicy = ExecPolicy()
+    # Encoded BSGS diagonals held on the device between applications; the
+    # contexts derived from this one (``with_policy``, ``with_keys``) share it.
+    diag_cache: linear.DiagCache = dataclasses.field(
+        default_factory=lambda: linear.DiagCache(linear.DIAG_CACHE_BYTES), repr=False, compare=False
+    )
 
     def __post_init__(self):
         # The scheme is ground truth on the params (plain_modulus set ⇔ BGV);

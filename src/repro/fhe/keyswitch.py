@@ -10,6 +10,13 @@ basis q_0..q_ℓ) multiplied by s' into a pair under s:
     4. accumulate  Σ_j  d̂_j ∘ ksk_j                       (MAC stage)
     5. ModDown by P: INTT(P limbs) → BConv P→Q → NTT → subtract, ×[P^{-1}]_q
 
+Both conversions are centred (``rns.centring_tables``): each prescaled
+residue enters as its representative in [−⌊b/2⌋, ⌈b/2⌉), so the digits and
+the ModDown remainder have mean zero.  Plain [0, b) residues give every
+coefficient a mean of ≈ k·B/2; times the key error (ModUp) or the secret
+(ModDown), that constant's canonical embedding grows like N at the slots
+next to ±1, and with it the decode error of every rotation.
+
 Two pipeline shapes execute the same math:
 
   * **fused** — stages 2–4 run as ONE `pallas_call` per key-switch (and one
@@ -31,7 +38,7 @@ Two pipeline shapes execute the same math:
 Every stage records trace instructions; this function *is* the workload the
 bootstrappable clusters are shaped around.  Each stage entry point
 runs under a ``ks.<stage>`` host span on the profiler's clock
-(``repro.kernels.dispatch``).
+(``repro.kernels.dispatch``), the rotation epilogue under ``ks.permute``.
 """
 
 from __future__ import annotations
@@ -76,28 +83,21 @@ def _boundary(n: int, limbs: int) -> None:
     trace.record("LOAD_WS", n, limbs)
 
 
-@functools.lru_cache(maxsize=2048)
-@dispatch.spanned("table.ks_digit")
-def _digit_tables(params: CkksParams, level: int, j: int):
-    """(src_idx, bhat_inv, w, dst_primes) for digit j at ``level``."""
+def _digit_primes(params: CkksParams, level: int, j: int):
+    """(digit_idx, src primes, dst primes) of digit j at ``level``."""
     digit_idx = tuple(i for i in params.digit(j) if i <= level)
-    src = poly.primes_for(params, digit_idx)
-    dst_idx = poly.ext_idx(params, level)
-    dst = poly.primes_for(params, dst_idx)
-    bhat_inv, w = rns.bconv_tables(src, dst)
-    return digit_idx, dispatch.upload(bhat_inv), dispatch.upload(w), np.array(dst, np.uint64)
+    return (digit_idx, poly.primes_for(params, digit_idx),
+            poly.primes_for(params, poly.ext_idx(params, level)))
 
 
 @functools.lru_cache(maxsize=512)
 @dispatch.spanned("table.ks_moddown")
-def _moddown_tables(params: CkksParams, level: int):
-    p_primes = poly.primes_for(params, poly.p_idx(params))
+def _moddown_pinv(params: CkksParams, level: int):
+    """(q primes, [P⁻¹]_q as a (level+1, 1) device column) of a ModDown at ``level``."""
     q_primes = poly.primes_for(params, poly.q_idx(params, level))
-    bhat_inv, w = rns.bconv_tables(p_primes, q_primes)
-    P = rns.product(p_primes)
+    P = rns.product(poly.primes_for(params, poly.p_idx(params)))
     pinv = np.array([pow(P % int(q), -1, int(q)) for q in q_primes], np.uint64)
-    return (dispatch.upload(bhat_inv), dispatch.upload(w), np.array(q_primes, np.uint64),
-            dispatch.upload(pinv[:, None], np.uint32))
+    return np.array(q_primes, np.uint64), dispatch.upload(pinv[:, None], np.uint32)
 
 
 def _scale_limbs(x, consts, qs, backend):
@@ -150,14 +150,15 @@ def mod_down(acc_ext, params: CkksParams, level: int, backend: str = "auto"):
     nq = level + 1
     alpha = params.alpha
     q_part, p_part = acc_ext[:nq], acc_ext[nq:]
-    bhat_inv, w, q_np, pinv = _moddown_tables(params, level)
-    p_np = np.array(poly.primes_for(params, poly.p_idx(params)), np.uint64)
+    q_np, pinv = _moddown_pinv(params, level)
 
     p_coeff = poly.to_coeff(p_part, params, poly.p_idx(params), stage)
-    xhat = _scale_limbs(p_coeff, bhat_inv, p_np, stage)
+    trace.record("PMULT", n, alpha)
     _boundary(n, alpha)
     trace.record("BCONV", n, alpha, dst=nq)
-    conv = bconv_ops.bconv(xhat, w, q_np, backend=stage)
+    conv = bconv_ops.conv_centred(
+        p_coeff, poly.primes_for(params, poly.p_idx(params)), q_np, backend=stage
+    )
     _boundary(n, nq)
     conv_eval = poly.to_eval(conv, params, poly.q_idx(params, level), stage)
     _boundary(n, nq)
@@ -188,6 +189,21 @@ def mod_down_pair(acc0, acc1, params: CkksParams, level: int, backend: str = "au
     q_part = jnp.stack([poly.limbs(acc0, 0, nq), poly.limbs(acc1, 0, nq)])
     out = fused_ops.mod_down_digits(p_coeff, q_part, params, level, backend="kernel")
     return lax.index_in_dim(out, 0, keepdims=False), lax.index_in_dim(out, 1, keepdims=False)
+
+
+def _mod_up_digit(d_coeff, params: CkksParams, level: int, j: int, stage: str):
+    """Staged ModUp of digit j: centred BConv onto the extended basis, then NTT."""
+    n = params.n
+    digit_idx, src, dst = _digit_primes(params, level, j)
+    k, m = len(digit_idx), len(dst)
+    trace.record("PMULT", n, k)
+    _boundary(n, k)
+    trace.record("BCONV", n, k, dst=m)
+    dj_ext = bconv_ops.conv_centred(
+        lax.slice_in_dim(d_coeff, digit_idx[0], digit_idx[-1] + 1), src, dst, backend=stage
+    )
+    _boundary(n, m)
+    return poly.to_eval(dj_ext, params, poly.ext_idx(params, level), stage)
 
 
 def key_switch(d_eval, params: CkksParams, level: int, ksk: SwitchingKey, backend: str = "auto"):
@@ -235,16 +251,7 @@ def key_switch_accumulate(d_eval, params: CkksParams, level: int, ksk_sel,
     acc0 = jnp.zeros((m, n), jnp.uint32)
     acc1 = jnp.zeros((m, n), jnp.uint32)
     for j in range(beta):
-        digit_idx, bhat_inv, w, dst = _digit_tables(params, level, j)
-        k = len(digit_idx)
-        src_np = np.array(poly.primes_for(params, digit_idx), np.uint64)
-        dj = d_coeff[digit_idx[0] : digit_idx[-1] + 1]
-        xhat = _scale_limbs(dj, bhat_inv, src_np, stage)
-        _boundary(n, k)
-        trace.record("BCONV", n, k, dst=m)
-        dj_ext = bconv_ops.bconv(xhat, w, dst, backend=stage)
-        _boundary(n, m)
-        dj_eval = poly.to_eval(dj_ext, params, ext, stage)
+        dj_eval = _mod_up_digit(d_coeff, params, level, j, stage)
         _boundary(n, m)
         trace.record("PMULT", n, 2 * m, mac=True)
         t0 = mo.pointwise_mulmod(dj_eval, ksk_sel[j, 0], ext_primes, backend=stage)
@@ -327,19 +334,7 @@ def hoisted_mod_up(d_eval, params: CkksParams, level: int, backend: str = "auto"
         _record_modup_digits(params, level)
         digits = hoist_ops.mod_up_digits(d_coeff, params, level, backend="kernel")
     else:
-        rows = []
-        for j in range(beta):
-            digit_idx, bhat_inv, w, dst = _digit_tables(params, level, j)
-            k = len(digit_idx)
-            src_np = np.array(poly.primes_for(params, digit_idx), np.uint64)
-            dj = d_coeff[digit_idx[0] : digit_idx[-1] + 1]
-            xhat = _scale_limbs(dj, bhat_inv, src_np, stage)
-            _boundary(n, k)
-            trace.record("BCONV", n, k, dst=m)
-            dj_ext = bconv_ops.bconv(xhat, w, dst, backend=stage)
-            _boundary(n, m)
-            rows.append(poly.to_eval(dj_ext, params, ext, stage))
-        digits = jnp.stack(rows)
+        digits = jnp.stack([_mod_up_digit(d_coeff, params, level, j, stage) for j in range(beta)])
     _boundary(n, beta * m)  # hoisted digits round-trip to the MAC launches
     return HoistedDigits(digits=digits, level=level)
 
@@ -348,8 +343,10 @@ def hoisted_mod_up(d_eval, params: CkksParams, level: int, backend: str = "auto"
 # level-restricted key itself — so the per-KeySet cache is LRU-bounded BY
 # BYTES (an entry count would still admit ~β·m·N-sized blowups at production
 # parameters: one N=2^16 deep entry is >100 MB).  An entry larger than the
-# whole budget is simply not cached.
-HOIST_KSK_CACHE_BYTES = 256 * 2**20
+# whole budget is simply not cached.  The budget holds the 22 Galois keys of
+# an LSTM gate's two BSGS matvecs at lstm's level 13 (22 MB each), so a job
+# that rotates through all of them permutes no key again.
+HOIST_KSK_CACHE_BYTES = 1 * 2**30
 
 
 def hoisted_ksk(params: CkksParams, keys: KeySet, t: int, level: int):
@@ -430,6 +427,7 @@ def mod_down_group(accs, params: CkksParams, level: int, backend: str = "auto"):
     return out.reshape(nrot, 2, nq, params.n)
 
 
+@dispatch.spanned("ks.permute")
 def permute_last(c0_eval, ks0, ks1, t: int, params: CkksParams, level: int,
                  backend: str = "auto"):
     """The shared rotation epilogue: c0 + ks0, then ONE σ_t per component.
@@ -461,5 +459,6 @@ def rotate_hoisted(c0_eval, hd: HoistedDigits, t: int, keys: KeySet, params: Ckk
     """
     ksk_stack = hoisted_ksk(params, keys, t, level)[None]
     accs = hoisted_galois_ks(hd, ksk_stack, params, level, backend)
-    ks = mod_down_group(accs, params, level, backend)
-    return permute_last(c0_eval, ks[0, 0], ks[0, 1], t, params, level, backend)
+    pair = lax.index_in_dim(mod_down_group(accs, params, level, backend), 0, keepdims=False)
+    ks0, ks1 = (lax.index_in_dim(pair, c, keepdims=False) for c in (0, 1))
+    return permute_last(c0_eval, ks0, ks1, t, params, level, backend)

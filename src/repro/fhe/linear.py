@@ -7,6 +7,11 @@ costs ≈ 2√n key-switched rotations + n plaintext multiplies — the dominant
 workload of CoeffToSlot/SlotToCoeff in bootstrapping (paper §3.3: rotation-
 heavy deep pipelines).
 
+The diagonals' encodings stay on the device between applications
+(``FheContext.diag_cache``, a ``DiagCache``), as a server holds its weight
+matrix encoded: an application encodes only what the cache misses, and every
+ct×pt product, add, rotation and rescale still runs.
+
 Execution policy comes from ``repro.fhe.context.FheContext`` —
 ``ctx.apply_bsgs``/``ctx.plan_matrix`` are the primary API, and
 ``plan_matrix`` picks the baby-step count n1 from a hoisting-aware cost model
@@ -18,12 +23,18 @@ remain at module level.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import hashlib
 import math
 
 import numpy as np
+from jax import lax
 
-from . import ops
+from repro.kernels import dispatch
+from repro.kernels.ntt import ops as ntt_ops
+
+from . import encoder, ops, poly, trace
 from .params import CkksParams
 
 
@@ -158,6 +169,100 @@ def plan_diags(diags: dict[int, np.ndarray], params: CkksParams, level: int | No
 
 
 # ---------------------------------------------------------------------------
+# server-resident encoded diagonals
+# ---------------------------------------------------------------------------
+
+# One lstm.matvec job applies 2 × 128 diagonals at 14 limbs of 2^16 words:
+# 0.94 GB resident.  The bound leaves room for that and evicts the least
+# recently used encoding beyond it.
+DIAG_CACHE_BYTES = 2 * 2**30
+
+
+class DiagCache:
+    """Device-resident eval-domain encodings of BSGS diagonals, LRU-bounded by bytes.
+
+    The key is the diagonal's content (a digest of its dtype, shape and
+    bytes), its pre-rotation, and the level, scale and limb set of the
+    encoding, plus the default device: never the array's identity, so a
+    changed diagonal misses and is encoded afresh.  An entry larger than the
+    whole budget is not kept.
+    """
+
+    def __init__(self, max_bytes: int):
+        self.max_bytes = max_bytes
+        self._entries: collections.OrderedDict = collections.OrderedDict()
+        self.nbytes = 0
+        self.hits = 0
+        self.misses = 0
+
+    def get(self, key):
+        data = self._entries.get(key)
+        if data is None:
+            self.misses += 1
+        else:
+            self._entries.move_to_end(key)
+            self.hits += 1
+        return data
+
+    def put(self, key, data) -> None:
+        size = int(data.nbytes)
+        if key in self._entries or size > self.max_bytes:  # a repeated diagonal, or too big
+            return
+        while self.nbytes + size > self.max_bytes:
+            _, old = self._entries.popitem(last=False)
+            self.nbytes -= int(old.nbytes)
+        self._entries[key] = data
+        self.nbytes += size
+
+    def stats(self) -> dict:
+        """{"entries", "bytes", "hits", "misses"}: what is resident now, and
+        the lookups since the process started."""
+        return {"entries": len(self._entries), "bytes": self.nbytes,
+                "hits": self.hits, "misses": self.misses}
+
+
+def _digest(v: np.ndarray) -> bytes:
+    v = np.ascontiguousarray(v)
+    h = hashlib.sha1(f"{v.dtype.str}{v.shape}".encode())
+    h.update(v)
+    return h.digest()
+
+
+@dispatch.spanned("table.diag")
+def _encode_batch(ctx, values: list[np.ndarray], level: int, scale: float):
+    """Slot vectors → (count, level+1, N) eval-domain encodings: one upload, one NTT."""
+    params = ctx.params
+    primes = params.q_primes[: level + 1]
+    coeffs = np.stack([encoder.encode(v, params.n, scale, primes) for v in values])
+    plan_q = poly.plan_for(params, poly.q_idx(params, level))
+    return ntt_ops.ntt_fwd(dispatch.upload(coeffs, np.uint32), plan_q, ctx.stage)
+
+
+def _encoded_diags(ctx, plan: BsgsPlan, ds: list[int], rot: int, level: int,
+                   scale: float) -> list[ops.Plaintext]:
+    """Plaintexts of the diagonals ``ds`` pre-rotated by ``rot`` slots.
+
+    Hits come from ``ctx.diag_cache``; the misses are encoded together, one upload
+    and one NTT, under a ``table.diag`` span.  Either way the op's
+    instruction stream reads each plaintext as a ``LOAD_PT``.
+    """
+    params = ctx.params
+    primes = params.q_primes[: level + 1]
+    keys = [(_digest(plan.diags[d]), rot, level, float(scale), primes,
+             dispatch.default_device()) for d in ds]
+    found = [ctx.diag_cache.get(k) for k in keys]
+    miss = [i for i, f in enumerate(found) if f is None]
+    if miss:
+        data = _encode_batch(ctx, [np.roll(plan.diags[ds[i]], rot) for i in miss], level, scale)
+        for j, i in enumerate(miss):
+            found[i] = lax.index_in_dim(data, j, keepdims=False)
+            ctx.diag_cache.put(keys[i], found[i])
+    for _ in ds:
+        trace.record("LOAD_PT", params.n, level + 1)
+    return [ops.Plaintext(data=f, level=level, scale=scale) for f in found]
+
+
+# ---------------------------------------------------------------------------
 # context implementations
 # ---------------------------------------------------------------------------
 
@@ -195,10 +300,9 @@ def _apply_bsgs(ctx, ct: ops.Ciphertext, plan: BsgsPlan,
     total: ops.Ciphertext | None = None
     for g, ds in sorted(by_giant.items()):
         acc: ops.Ciphertext | None = None
-        for d in ds:
+        pts = _encoded_diags(ctx, plan, ds, g * plan.n1, lv, scale)  # pre-rotated diagonals
+        for d, pt in zip(ds, pts):
             b = d % plan.n1
-            u = np.roll(plan.diags[d], g * plan.n1)  # pre-rotate the diagonal
-            pt = ops._encode(ctx, u, level=lv, scale=scale)
             term = ops._mul_plain(ctx, babies[b], pt, rescale_after=False)
             acc = term if acc is None else ops._add(ctx, acc, term)
         if g:

@@ -396,7 +396,9 @@ def _rotate_hoisted_group(ctx, ct: Ciphertext, rots, keys: KeySet) -> dict[int, 
     ks = keyswitch.mod_down_group(accs, params, lv, backend)
     by_rm: dict[int, Ciphertext] = {}
     for i, (rm, t) in enumerate(uniq.items()):
-        c0, c1 = keyswitch.permute_last(ct.c0, ks[i, 0], ks[i, 1], t, params, lv, backend)
+        pair = lax.index_in_dim(ks, i, keepdims=False)  # static slices: no index transfer
+        ks0, ks1 = (lax.index_in_dim(pair, c, keepdims=False) for c in (0, 1))
+        c0, c1 = keyswitch.permute_last(ct.c0, ks0, ks1, t, params, lv, backend)
         by_rm[rm] = Ciphertext(c0=c0, c1=c1, level=lv, scale=ct.scale)
     return {r: (by_rm[r % params.slots] if r % params.slots else ct) for r in rots}
 

@@ -32,6 +32,27 @@ def bconv_tables(src: tuple[int, ...], dst: tuple[int, ...]):
     return bhat_inv, w
 
 
+@functools.lru_cache(maxsize=512)
+def centring_tables(src: tuple[int, ...], dst: tuple[int, ...]):
+    """Tables that centre Conv_{src→dst}.
+
+    Returns (half, corr):
+      half[i] = ⌊b_i/2⌋                                — (k,) uint32
+      corr[j] = Σ_i half[i]·(B/b_i) mod c_j             — (m,) uint32
+
+    Adding half[i] to each pre-scaled row mod b_i, converting, and subtracting
+    corr sums the rows' centred representatives in [−⌊b_i/2⌋, ⌈b_i/2⌉)
+    instead of [0, b_i).  The converted integer is then ≡ x (mod B) with
+    mean zero; the plain conversion's lies in [0, k·B) with mean ≈ k·B/2, a
+    constant on every coefficient whose canonical embedding grows like N at
+    the slots next to ±1 (key-switch noise that grows with N).
+    """
+    B = product(src)
+    half = np.array([b // 2 for b in src], np.uint32)
+    corr = np.array([sum((b // 2) * (B // b) for b in src) % c for c in dst], np.uint32)
+    return half, corr
+
+
 def crt_reconstruct_centered(residues: np.ndarray, primes, max_limbs: int = 4) -> np.ndarray:
     """Centered CRT over the first ≤ max_limbs primes (object-int array).
 

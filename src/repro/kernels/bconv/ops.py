@@ -1,13 +1,20 @@
-"""Public BConv op: pads limb counts to multiples of 8 and dispatches kernel/ref."""
+"""Public BConv ops: the plain conversion (pads limb counts to multiples of 8
+and dispatches kernel/ref) and the centred conversion the key-switch runs."""
 
 from __future__ import annotations
 
+import functools
+from typing import NamedTuple
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.fhe import modmath as mm
+from repro.fhe import rns
 from repro.fhe.ntt import NDIAG, NLIMB8
 from repro.kernels import dispatch, tpu
+from repro.kernels.modops import ops as mo
 
 from . import kernel as _k
 from . import ref as _ref
@@ -50,3 +57,39 @@ def bconv(xhat, w, cs, backend: str = "auto"):
             interpret=not tpu.on_tpu(),
         )
         return out[:m]
+
+
+class ConvTables(NamedTuple):
+    """Device constants of one centred conversion src → dst (``rns`` tables)."""
+
+    bhat_inv: jax.Array  # (k, 1) [B̂_i⁻¹]_{b_i}
+    half: jax.Array  # (k, 1) ⌊b_i/2⌋
+    w: jax.Array  # (k, m) B̂_i mod c_j
+    corr: jax.Array  # (m, 1) Σ_i ⌊b_i/2⌋·B̂_i mod c_j
+
+
+@functools.lru_cache(maxsize=1024)
+@dispatch.spanned("table.bconv")
+def _conv_tables(src: tuple[int, ...], dst: tuple[int, ...], device) -> ConvTables:
+    """Built once per (src, dst) and default ``device``, like ``mo.limb_constants``."""
+    bhat_inv, w = rns.bconv_tables(src, dst)
+    half, corr = rns.centring_tables(src, dst)
+    return ConvTables(dispatch.upload(bhat_inv[:, None]), dispatch.upload(half[:, None]),
+                      dispatch.upload(w), dispatch.upload(corr[:, None]))
+
+
+def conv_centred(x, src, dst, backend: str = "auto"):
+    """Centred fast basis conversion of coefficient limbs x: (k, N) over the
+    primes ``src`` → (m, N) over ``dst``.
+
+    Each row enters as the centred representative of [x_i·B̂_i⁻¹]_{b_i}
+    (``rns.centring_tables``), so the converted integer has mean zero.  The
+    staged form of the region the fused key-switch kernels run in one program:
+    prescale, centre, convert, correct.
+    """
+    tb = _conv_tables(tuple(int(b) for b in src), tuple(int(c) for c in dst),
+                      dispatch.default_device())
+    y = mo.pointwise_mulmod(x, jnp.broadcast_to(tb.bhat_inv, x.shape), src, backend=backend)
+    y = mo.pointwise_addmod(y, jnp.broadcast_to(tb.half, x.shape), src, backend=backend)
+    conv = bconv(y, tb.w, dst, backend=backend)
+    return mo.pointwise_submod(conv, jnp.broadcast_to(tb.corr, conv.shape), dst, backend=backend)

@@ -16,13 +16,15 @@ time on the calling thread:
 
 * ``fhe.<method>``: one public ``FheContext`` method, end to end on the host;
 * ``ks.<stage>``: one key-switch stage of ``repro.fhe.keyswitch``
-  (``accumulate``, ``moddown``, ``modup``, ``mac``, ``moddown_group``);
+  (``accumulate``, ``moddown``, ``modup``, ``mac``, ``moddown_group``, and
+  ``permute``, the rotation epilogue);
 * ``kernel.<op>``: the host side of one kernel launch (``launch``): table
   lookups, reshapes, uploads of operands still on the host, and the jitted
   kernel call;
 * ``h2d``: one host-to-device transfer of a numpy or Python value (``upload``);
 * ``table.<name>``: one build of a cached table, inside the ``lru_cache``d
-  function that builds it, so it fires only on a cache miss.
+  function that builds it, so it fires only on a cache miss; ``table.diag``
+  is one batch of BSGS diagonals encoded on a miss of ``FheContext.diag_cache``.
 
 Counts and spans happen at Python call time, so inside an enclosing
 `jax.jit` both fire at trace time only (once per compilation): the counts are

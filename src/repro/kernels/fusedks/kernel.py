@@ -11,14 +11,17 @@ never leave VMEM:
   accumulators stays resident in VMEM while all β digits stream through it.
   One program:
 
-    1. prescale   x̂_i = x_i ∘ [B̂_i⁻¹]_{b_i}        (one Montgomery mul/row)
-    2. BConv row  y_e = Σ_i x̂_i · (B̂_i mod c_e)     (one Montgomery mul/row)
+    1. prescale   x̂_i = x_i ∘ [B̂_i⁻¹]_{b_i} + ⌊b_i/2⌋  (Montgomery mul + add/row)
+    2. BConv row  y_e = Σ_i x̂_i · (B̂_i mod c_e) − C_e  (one Montgomery mul/row)
     3. NTT        ŷ_e = NTT_{c_e}(y_e)               (four-step MXU matmuls)
     4. KSK MAC    acc_{0,1}[e] += ŷ_e ∘ ksk_{j,{0,1}}[e]   (both components)
 
 Stages 1–2 run on the VPU: one output row of a BConv is a k-term dot product,
 too thin for the MXU.  The weights arrive in Montgomery form, so each term is
-one Montgomery multiply of x̂_i (< b_i < 2^31) by [B̂_i·R]_{c_e}.  Digits are
+one Montgomery multiply of x̂_i (< b_i < 2^31) by [B̂_i·R]_{c_e}.  The added
+⌊b_i/2⌋ and the subtracted C_e = Σ_i ⌊b_i/2⌋·B̂_i mod c_e make the conversion
+sum each row's centred representative (``fhe.rns.centring_tables``), so the
+key-switch noise has mean zero and does not grow with N.  Digits are
 zero-padded to a uniform row count k = α (padded rows carry a dummy modulus and
 zero weights, exact no-ops), so all β digits and both key components ride one
 grid.  A second entry point runs the same pipeline with a ModDown epilogue —
@@ -59,8 +62,8 @@ from repro.kernels import tpu
 from repro.kernels.ntt.kernel import _addmod, _montmul, limb_scalars, ntt_fwd_tile
 
 # per (digit, row) prescale constants in the flat SMEM table
-DB, DBINV, DBH = 0, 1, 2  # b, -b⁻¹ mod 2³², [B̂⁻¹]·R mod b
-NDSC = 3
+DB, DBINV, DBH, DHALF = 0, 1, 2, 3  # b, -b⁻¹ mod 2³², [B̂⁻¹]·R mod b, ⌊b/2⌋
+NDSC = 4
 
 
 def fused_vmem_bytes(k: int, n1: int, n2: int) -> int:
@@ -75,17 +78,20 @@ def fused_vmem_bytes(k: int, n1: int, n2: int) -> int:
 
 
 def _bconv_row(x_ref, dsc_ref, wm_ref, j, e, q, qinv):
-    """Stages 1+2 for digit j → ext limb e: Σ_i x̂_i·B̂_i mod c_e, on the VPU."""
+    """Stages 1+2 for digit j → ext limb e: the centred Σ_i x̂_i·B̂_i mod c_e,
+    on the VPU.  ``wm`` holds k+1 rows of m words per digit: the k weight rows,
+    then the row of c_e − C_e."""
     k = x_ref.shape[0]
-    m = wm_ref.shape[0] // (dsc_ref.shape[0] // NDSC)  # weights per source row
+    m = wm_ref.shape[0] // ((dsc_ref.shape[0] // (NDSC * k)) * (k + 1))
     y = None
     for i in range(k):
         row = j * k + i
         b = dsc_ref[row * NDSC + DB]
         xh = _montmul(x_ref[i], dsc_ref[row * NDSC + DBH], b, dsc_ref[row * NDSC + DBINV])
-        t = _montmul(xh, wm_ref[row * m + e], q, qinv)
+        xh = _addmod(xh, dsc_ref[row * NDSC + DHALF], b)
+        t = _montmul(xh, wm_ref[(row + j) * m + e], q, qinv)
         y = t if y is None else _addmod(y, t, q)
-    return y
+    return _addmod(y, wm_ref[(j * (k + 1) + k) * m + e], q)
 
 
 def _modup_limb(sc_ref, dsc_ref, wm_ref, x_ref, twa_ref, v2_ref, v1_ref, t_ref, j, e):
@@ -134,7 +140,8 @@ def fused_ks_pallas(xd, sc, dsc, wm, twa, v2, v1, t, ksk, *, interpret):
 
     xd:  (β, k, N2, N1) digit source limbs (coeff domain, rows zero-padded)
     sc:  (m·NSC,) ext-basis limb scalars; dsc: (β·k·NDSC,) prescale constants;
-    wm:  (β·k·m,) Montgomery BConv weights [B̂_i·R]_{c_e}
+    wm:  (β·(k+1)·m,) per digit, k rows of Montgomery BConv weights
+         [B̂_i·R]_{c_e}, then one row of centring corrections c_e − C_e
     twa/v2/v1/t: ext-basis forward NTT tables, leading (m, ...) axis
     ksk: (β, 2, m, N1, N2) switching-key limbs (eval domain)
     Returns (m, 2, N1, N2): the two MAC accumulators over the extended basis.
@@ -176,7 +183,7 @@ def fused_moddown_pallas(pc, sc, dsc, wm, pinv, twa, v2, v1, t, qpart, *, interp
            iNTT (C = 2 for one key-switch's pair; C = 2·R when a hoisted
            rotation group ModDowns every rotation's pair in one launch)
     sc:    (m·NSC,) q-basis limb scalars; dsc/wm: prescale constants and
-           Montgomery BConv weights of the special block (one digit)
+           Montgomery BConv weights (and centring row) of the special block
     pinv:  (m,) Montgomery [P⁻¹]_{q_e};  qpart: (C, m, N1, N2) eval q limbs
     NTT tables carry the q-basis (m = level+1 limbs).  Returns (C, m, N1, N2).
     """

@@ -60,27 +60,32 @@ class KsTables:
     k: int  # rows per digit block (α; short digits are zero-padded)
     m: int
     spans: tuple[tuple[int, int], ...]  # (lo, hi) master-chain slice per digit
-    dsc: jnp.ndarray  # (β·k·NDSC,) prescale constants, layout ``kernel.DB..DBH``
-    wm: jnp.ndarray  # (β·k·m,) Montgomery BConv weights [B̂_i·R]_{c_e}
+    dsc: jnp.ndarray  # (β·k·NDSC,) prescale constants, layout ``kernel.DB..DHALF``
+    wm: jnp.ndarray  # (β·(k+1)·m,) Montgomery BConv weights [B̂_i·R]_{c_e}, centring row
     ntt: ntt_ops.KernelTables  # forward NTT over the destination basis
     zero: jnp.ndarray  # () uint32 pad value of the digit blocks (``pack_digits``)
 
 
 def _prescale_tables(digits: list[tuple[int, ...]], dst_primes, k: int):
-    """Flat (dsc, wm) SMEM tables for the digit list, rows zero-padded to k."""
+    """Flat (dsc, wm) SMEM tables for the digit list, rows zero-padded to k;
+    each digit's k weight rows are followed by its centring row c_e − C_e."""
     nd = len(digits)
-    dst = np.array(dst_primes, np.uint64)
+    dst_t = tuple(int(c) for c in dst_primes)
+    dst = np.array(dst_t, np.uint64)
     dsc = np.zeros((nd, k, _k.NDSC), np.uint32)
     dsc[..., _k.DB] = _PAD_MOD
     dsc[..., _k.DBINV] = mm.MontConstants(_PAD_MOD).qinv_neg
-    wm = np.zeros((nd, k, len(dst)), np.uint32)
+    wm = np.zeros((nd, k + 1, len(dst)), np.uint32)
     for j, src in enumerate(digits):
         n = len(src)
-        bhat_inv, wj = rns.bconv_tables(src, tuple(int(c) for c in dst_primes))
+        bhat_inv, wj = rns.bconv_tables(src, dst_t)
+        half, corr = rns.centring_tables(src, dst_t)
         dsc[j, :n, _k.DB] = np.array(src, np.uint32)
         dsc[j, :n, _k.DBINV] = mm.mont_constants_array(list(src))["qinv_neg"]
         dsc[j, :n, _k.DBH] = [(int(bhat_inv[i]) << 32) % int(b) for i, b in enumerate(src)]
+        dsc[j, :n, _k.DHALF] = half
         wm[j, :n] = (np.asarray(wj, np.uint64) << np.uint64(32)) % dst
+        wm[j, k] = (dst - corr) % dst
     return dispatch.upload(dsc.reshape(-1)), dispatch.upload(wm.reshape(-1))
 
 

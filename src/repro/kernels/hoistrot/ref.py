@@ -12,24 +12,14 @@ import numpy as np
 
 from repro.fhe import poly
 from repro.fhe.params import CkksParams
-from repro.kernels.bconv import ops as bconv_ops
-from repro.kernels.fusedks.ref import _digit_ref_tables, _scale
+from repro.kernels.fusedks.ref import mod_up_digit_ref
 from repro.kernels.modops import ops as mo
-from repro.kernels.ntt import ops as ntt_ops
 
 
 def mod_up_digits_ref(d_coeff, params: CkksParams, level: int):
     """(level+1, N) coeff limbs → (β, m, N) eval-domain extended-basis digits."""
-    ext = poly.ext_idx(params, level)
-    ext_primes = np.array(poly.primes_for(params, ext), np.uint64)
-    plan = poly.plan_for(params, ext)
-    rows = []
-    for j in range(params.beta(level)):
-        lo, hi, src_np, bhat_inv, w = _digit_ref_tables(params, level, j)
-        xhat = _scale(d_coeff[lo:hi], bhat_inv, src_np)
-        dj_ext = bconv_ops.bconv(xhat, w, ext_primes, backend="ref")
-        rows.append(ntt_ops.ntt_fwd(dj_ext, plan, "ref"))
-    return jnp.stack(rows)
+    return jnp.stack([mod_up_digit_ref(d_coeff, params, level, j)
+                      for j in range(params.beta(level))])
 
 
 def galois_mac_ref(dig, ksk, params: CkksParams, level: int, stage: str = "ref"):

@@ -111,8 +111,9 @@ def test_fused_issues_fewer_dispatches(ks_setup):
     # one fused ModDown launch
     assert dispatch.total(cf) == 4
     assert cf["fusedks"] == 1 and cf["fused_moddown"] == 1
-    # staged: 7 launches per digit + 2×6 ModDown + shared iNTT
-    assert dispatch.total(cs) == 7 * beta + 13
+    # staged: 9 launches per digit (prescale, centre, BConv, correct, NTT,
+    # two MACs, two accumulates) + 2×8 ModDown + shared iNTT
+    assert dispatch.total(cs) == 9 * beta + 17
     assert dispatch.total(cf) < dispatch.total(cs)
 
 

@@ -137,7 +137,7 @@ def test_fused_shape_rule_matches_compiler(k, fits, one_chip):
 
     lowered = fused_k.fused_ks_pallas.lower(
         spec((beta, k, n2, n1)), spec((m * ntt_k.NSC,)), spec((beta * k * fused_k.NDSC,)),
-        spec((beta * k * m,)), spec((m, n1, n2)), spec((m, NLIMB8, n2, n2), jnp.bfloat16),
+        spec((beta * (k + 1) * m,)), spec((m, n1, n2)), spec((m, NLIMB8, n2, n2), jnp.bfloat16),
         spec((m, NLIMB8, n1, n1), jnp.bfloat16), spec((m, n1, n2)),
         spec((beta, 2, m, n1, n2)), interpret=False,
     )

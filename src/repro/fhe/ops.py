@@ -18,8 +18,9 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import NamedTuple
+from typing import Callable, NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -241,7 +242,76 @@ def _mul_const_exact(ctx, a: Ciphertext, c, target_scale: float) -> Ciphertext:
 
 def _mul(ctx, a: Ciphertext, b: Ciphertext, rlk: SwitchingKey,
          rescale_after: bool = True) -> Ciphertext:
-    """Full homomorphic multiplication with relinearisation (key-switch of d2)."""
+    """Full homomorphic multiplication with relinearisation (key-switch of d2).
+
+    One device dispatch: ``_mul_eager`` traced once per (params, policy,
+    input levels, ``rescale_after``, default device) into a compiled program
+    (``_mul_program``), bit-exact against running it eagerly.  Inside an
+    enclosing trace (a caller's ``jax.jit``) the body runs inline instead.
+    Levels and scales stay on the host."""
+    xs = (a.c0, a.c1, b.c0, b.c1, rlk.k)
+    if any(isinstance(x, jax.core.Tracer) for x in xs):
+        return _mul_eager(ctx, a, b, rlk, rescale_after)
+    prog = _mul_program(ctx.params, ctx.policy, (a.level, b.level), rescale_after, rlk.k.shape,
+                        dispatch.default_device())
+    dispatch.replay(prog.counts)
+    trace.replay(prog.instrs)
+    c0, c1 = prog.run(prog.consts, *xs)
+    level, scale = min(a.level, b.level), a.scale * b.scale
+    if rescale_after:
+        level, scale = level - 1, scale / int(ctx.params.q_primes[level])
+    return Ciphertext(c0=c0, c1=c1, level=level, scale=scale)
+
+
+class _MulProgram(NamedTuple):
+    """A compiled ``_mul_eager`` and what its trace recorded."""
+
+    run: Callable  # jitted (consts, a.c0, a.c1, b.c0, b.c1, rlk.k) -> (c0, c1)
+    consts: list  # the device tables the body reads: arguments, never embedded constants
+    counts: dict  # kernel launches the body made ({op: n}), replayed on every call
+    instrs: list  # its planner trace records, replayed likewise
+
+
+@functools.lru_cache(maxsize=256)
+@dispatch.spanned("table.mul_program")
+def _mul_program(params: CkksParams, policy, levels: tuple[int, int], rescale_after: bool,
+                 rlk_shape, device) -> _MulProgram:
+    """Trace ``_mul_eager`` once under a key-less context of ``params`` and
+    ``policy``, at these input levels.  Every device array the body closes
+    over (limb constants, NTT, key-switch and rescale tables) comes out of
+    ``make_jaxpr`` as a const and is passed to the program as an argument:
+    captured, it would be embedded in the HLO.  The key is an argument of
+    every call, so one program serves every key set of these params."""
+    from .context import FheContext  # context imports this module
+
+    ctx = FheContext(params=params, policy=policy)
+
+    def body(a0, a1, b0, b1, k):
+        out = _mul_eager(ctx, Ciphertext(a0, a1, levels[0], 1.0), Ciphertext(b0, b1, levels[1], 1.0),
+                         SwitchingKey(k), rescale_after)
+        return out.c0, out.c1
+
+    shapes = [(lv + 1, params.n) for lv in (levels[0],) * 2 + (levels[1],) * 2] + [rlk_shape]
+    with dispatch.count_dispatches() as counts, trace.capture_trace() as instrs:
+        closed = jax.make_jaxpr(body)(*(jax.ShapeDtypeStruct(s, jnp.uint32) for s in shapes))
+
+    def ckks_mul(consts, *xs):
+        return jax.core.eval_jaxpr(closed.jaxpr, consts, *xs)
+
+    # the oracle's NTT plans are numpy: upload them once, here
+    consts = [dispatch.upload(c) for c in closed.consts]
+    return _MulProgram(jax.jit(ckks_mul), consts, dict(counts), list(instrs))
+
+
+def mul_program_stats() -> dict:
+    """{"programs": cached, "calls": compiled muls run, "builds": programs traced}."""
+    info = _mul_program.cache_info()
+    return {"programs": info.currsize, "calls": info.hits + info.misses, "builds": info.misses}
+
+
+def _mul_eager(ctx, a: Ciphertext, b: Ciphertext, rlk: SwitchingKey,
+               rescale_after: bool = True) -> Ciphertext:
+    """The multiply, one kernel launch at a time: the body ``_mul`` compiles."""
     params = ctx.params
     a, b = _align_mul(params, a, b)
     qs = _qs(params, a.level)

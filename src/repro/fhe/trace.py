@@ -31,6 +31,14 @@ def record(op: str, n: int, limbs: int, **meta) -> None:
         t.append(Instr(op, n, limbs, meta))
 
 
+def replay(instrs) -> None:
+    """Append ``instrs`` to the ambient trace, if any: a compiled program
+    records what its body recorded when it was traced, on every call."""
+    t = _TRACE.get()
+    if t is not None:
+        t.extend(instrs)
+
+
 @contextlib.contextmanager
 def capture_trace():
     token = _TRACE.set([])

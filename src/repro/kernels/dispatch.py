@@ -29,7 +29,9 @@ time on the calling thread:
 Counts and spans happen at Python call time, so inside an enclosing
 `jax.jit` both fire at trace time only (once per compilation): the counts are
 then the static dispatch count of the compiled program, and the spans cover
-tracing, not execution.
+tracing, not execution.  A CKKS ct×ct multiply is such a program
+(``repro.fhe.ops._mul_program``): its spans fire while it is built, under a
+``table.mul_program`` span, and each call ``replay``s the counts of that build.
 """
 
 from __future__ import annotations
@@ -105,6 +107,15 @@ def count_dispatches():
         yield _COUNTS.get()
     finally:
         _COUNTS.reset(token)
+
+
+def replay(counts: dict) -> None:
+    """Add ``counts`` to the active counter, if any: a compiled program counts
+    the launches its trace made on every call."""
+    c = _COUNTS.get()
+    if c is not None:
+        for op, n in counts.items():
+            c[op] = c.get(op, 0) + n
 
 
 def total(counts: dict) -> int:

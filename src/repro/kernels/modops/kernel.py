@@ -68,14 +68,14 @@ def _rowwise(name, body, scalars, a, b, interpret):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def mulmod_pallas(a, b, q, qinv, r2, *, interpret):
     """a, b: (..., l, N) uint32; q/qinv/r2: (l,) uint32 per-limb constants."""
-    return _rowwise("mulmod", _mul_body, (q, qinv, r2), a, b, interpret)
+    return _rowwise("mulmod_pallas", _mul_body, (q, qinv, r2), a, b, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def addmod_pallas(a, b, q, *, interpret):
-    return _rowwise("addmod", _add_body, (q,), a, b, interpret)
+    return _rowwise("addmod_pallas", _add_body, (q,), a, b, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def submod_pallas(a, b, q, *, interpret):
-    return _rowwise("submod", _sub_body, (q,), a, b, interpret)
+    return _rowwise("submod_pallas", _sub_body, (q,), a, b, interpret)

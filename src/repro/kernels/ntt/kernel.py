@@ -152,6 +152,6 @@ def ntt_pallas(x, sc, tw, v2, v1, t, *, inverse, interpret):
         ],
         out_specs=pl.BlockSpec((None, None) + out, lambda l, b: (b, l, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, nlimb) + out, jnp.uint32),
-        name="intt" if inverse else "ntt",
+        name="intt_pallas" if inverse else "ntt_pallas",
         interpret=interpret,
     )

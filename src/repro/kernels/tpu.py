@@ -6,7 +6,10 @@
   program.  ``resolve("auto")`` is "kernel" on a TPU and the uint64 oracle
   ("ref") elsewhere.
 * ``call`` traces ``pl.pallas_call`` with 64-bit types off, named after the
-  wrapper's op in ``repro.kernels.dispatch.KERNEL_OPS``.  ``repro.fhe``
+  jitted wrapper that calls it (``fused_ks_pallas``, ``mulmod_pallas``, ...;
+  ``ntt_pallas`` and ``intt_pallas`` for the two NTT directions), so a
+  profiler trace attributes the kernel by its op name inside a larger
+  compiled program as well as by the wrapper's own module when eager.  ``repro.fhe``
   enables x64 process-wide for its uint64 oracle, and under x64 the grid
   indices and index-map results become i64, which Mosaic cannot lower.  Every
   kernel here is uint32/bf16 already, so nothing else changes.
@@ -45,7 +48,7 @@ def smem() -> pl.BlockSpec:
 
 def call(kernel, args, *, name: str, grid, in_specs, out_specs, out_shape, interpret: bool):
     """``pl.pallas_call(kernel, ...)(*args)`` traced with 32-bit index types,
-    under the stable kernel ``name`` (the wrapper's ``dispatch.launch`` op)."""
+    under the stable kernel ``name`` (after the wrapper, as above)."""
     with jax.enable_x64(False):
         return pl.pallas_call(
             kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,

@@ -2,7 +2,10 @@
 
 Every kernel runs in the Pallas interpreter at N=2^10 (``backend="kernel"``,
 the pipeline a TPU runs by default), with ``TraceAnnotation`` replaced by a
-recorder that notes each span's name and the spans open around it.
+recorder that notes each span's name and the spans open around it.  A
+ciphertext multiply is one compiled program (``ops._mul_program``), so its
+``ks.*`` and ``kernel.*`` spans fire while it is built; a rotation still runs
+eagerly, launch by launch.
 """
 
 import collections
@@ -27,7 +30,8 @@ PROGRAM_PREFIXES = ("fhe.", "ks.", "kernel.", "h2d", "table.")
 @pytest.fixture(scope="module")
 def kctx():
     p = P.make_params(1 << 10, 3, 2, check_security=False)
-    ctx = FheContext(params=p, keys=K.full_keyset(p, seed=0), policy=ExecPolicy(backend="kernel"))
+    ctx = FheContext(params=p, keys=K.full_keyset(p, seed=0, rotations=(1,)),
+                     policy=ExecPolicy(backend="kernel"))
     rng = np.random.default_rng(5)
     a, b = (ctx.encrypt(ctx.encode(rng.uniform(-1, 1, p.slots)), seed=s) for s in (1, 2))
     return ctx, a, b
@@ -54,39 +58,63 @@ def spans(monkeypatch):
 
 
 def test_mul_spans_nest_by_layer(kctx, spans):
+    """Warm, a rotation nests fhe ⊃ ks ⊃ kernel (a multiply, compiled, opens
+    these spans only while it is built: ``test_table_spans_fire_on_cache_misses_only``)."""
     ctx, a, b = kctx
-    ctx.mul(a, b)
+    ctx.rotate(a, 1)
     spans.clear()
-    ctx.mul(a, b)  # warm: every table is built
-    assert spans[0] == ("fhe.mul", ())
-    assert all(outer[:1] == ("fhe.mul",) for _, outer in spans[1:])
+    ctx.rotate(a, 1)  # warm: every table is built
+    assert spans[0] == ("fhe.rotate", ())
+    assert all(outer[:1] == ("fhe.rotate",) for _, outer in spans[1:])
     assert all(name.startswith(PROGRAM_PREFIXES) for name, _ in spans)
     nest = {(name, outer[-1]) for name, outer in spans if outer}
-    assert ("ks.accumulate", "fhe.mul") in nest
-    assert ("ks.moddown", "fhe.mul") in nest
+    assert ("ks.accumulate", "fhe.rotate") in nest
+    assert ("ks.moddown", "fhe.rotate") in nest
     assert ("kernel.fusedks", "ks.accumulate") in nest
     assert ("kernel.fused_moddown", "ks.moddown") in nest
     assert not any(name == "h2d" and any(o.startswith("kernel.") for o in outer)
                    for name, outer in spans)
 
 
+
 def test_kernel_spans_match_dispatch_counts(kctx, spans):
+    """An eager op counts one dispatch per ``kernel.*`` span; a warm multiply
+    opens none and replays the eager body's count."""
     ctx, a, b = kctx
+    ctx.rotate(a, 1)
+    spans.clear()
     with dispatch.count_dispatches() as counts:
-        ctx.mul(a, b)
+        ctx.rotate(a, 1)
     launched = collections.Counter(n.removeprefix("kernel.") for n, _ in spans if n.startswith("kernel."))
     assert launched == counts
+    assert counts["fusedks"] == 1 and counts["fused_moddown"] == 1
+
+    ctx.mul(a, b)
+    spans.clear()
+    fhe_ops._mul_eager(ctx, a, b, ctx.keys.rlk)
+    eager = collections.Counter(n.removeprefix("kernel.") for n, _ in spans if n.startswith("kernel."))
+    spans.clear()
+    with dispatch.count_dispatches() as counts:
+        ctx.mul(a, b)
+    assert not any(n.startswith("kernel.") for n, _ in spans)
+    assert counts == eager
     assert counts["fusedks"] == 1 and counts["fused_moddown"] == 1
 
 
 def test_table_spans_fire_on_cache_misses_only(kctx, spans):
     ctx, a, b = kctx
     for cached in (fops.ks_tables, fops.moddown_tables, ntt_ops.kernel_tables, mo._limb_tables,
-                   fhe_ops._rescale_tables, nttmod.subplan):
+                   fhe_ops._rescale_tables, nttmod.subplan, fhe_ops._mul_program):
         cached.cache_clear()
     ctx.mul(a, b)
     built = {n for n, _ in spans if n.startswith("table.")}
-    assert {"table.ks", "table.moddown", "table.ntt", "table.limbs", "table.rescale"} <= built
+    assert {"table.ks", "table.moddown", "table.ntt", "table.limbs", "table.rescale",
+            "table.mul_program"} <= built
+    nest = {(name, outer[-1]) for name, outer in spans if outer}
+    assert ("table.mul_program", "fhe.mul") in nest
+    assert ("ks.accumulate", "table.mul_program") in nest
+    assert ("kernel.fusedks", "ks.accumulate") in nest
+    assert ("kernel.fused_moddown", "ks.moddown") in nest
     spans.clear()
     ctx.mul(a, b)
     assert [n for n, _ in spans if n.startswith("table.")] == []

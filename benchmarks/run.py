@@ -1,4 +1,4 @@
-"""Benchmark harness: one entry per paper table/figure + the roofline table.
+"""Benchmark harness: one entry per paper table/figure.
 
 Emits ``name,value,derived`` CSV rows (derived=1 marks numbers reconstructed
 from the paper's reported ratios rather than simulated from architecture).
@@ -158,7 +158,7 @@ def emit_faults(emit, smoke: bool) -> None:
 
 
 def emit_paper_figs(emit) -> None:
-    from . import paper_figs, roofline_table
+    from . import paper_figs
 
     fig9 = paper_figs.fig9_single_workload()
     emit("fig9.deep_geomean_vs_craterlake", fig9["deep_geomean_vs_craterlake"])
@@ -207,13 +207,6 @@ def emit_paper_figs(emit) -> None:
         emit(f"perf.{w}.baseline_ms", row["baseline_ms"])
         emit(f"perf.{w}.optimized_ms", row["optimized_ms"])
         emit(f"perf.{w}.speedup", row["speedup"])
-
-    rt = roofline_table.main()
-    emit("roofline.cells_ok", rt["summary"]["ok"])
-    emit("roofline.cells_skipped", rt["summary"]["skipped"])
-    emit("roofline.cells_failed", rt["summary"]["failed"])
-    for dom, n in rt["dominant_histogram"].items():
-        emit(f"roofline.dominant.{dom}", n)
 
 
 def main(argv=None) -> None:

@@ -5,11 +5,7 @@ Layout:
   repro.kernels    Pallas TPU kernels (+ jit wrappers + pure-jnp oracles)
   repro.core       the paper's contribution: heterogeneous clusters + multi-job scheduler
   repro.serve      discrete-event multi-tenant serving (§4.2 online policy, traffic, SLOs)
-  repro.models     assigned LM architectures (dense / MoE / SSM / hybrid / enc-dec / VLM)
-  repro.training   optimizer + train step substrate
-  repro.serving    KV cache + decode substrate
-  repro.distributed / repro.launch   mesh, sharding rules, dry-run
-  repro.roofline   HLO-derived roofline terms
+  repro.obs        observability: span tracing, Perfetto export, metrics, perf history
 """
 
 __version__ = "1.0.0"

@@ -159,12 +159,3 @@ def swift_logic_fraction(node: str = "14nm") -> float:
     """Paper claim: swift-cluster logic < 7% of total chip area."""
     col = 0 if node == "7nm" else 1
     return AREA_TABLE_MM2["swift_clusters_total"][col] / AREA_TABLE_MM2["total"][col]
-
-
-# ---------------------------------------------------------------------------
-# TPU roofline constants (the JAX runtime target: v5e-class chips)
-# ---------------------------------------------------------------------------
-
-TPU_PEAK_FLOPS_BF16 = 197e12  # FLOP/s per chip
-TPU_HBM_GBPS = 819e9  # bytes/s per chip
-TPU_ICI_GBPS = 50e9  # bytes/s per link

@@ -11,7 +11,7 @@ fallback drives each ``@given`` test with seeded pseudo-random examples —
 enough to keep the property tests meaningful and the suite collectable on a
 bare runtime, while real hypothesis (shrinking, database, edge-case bias) is
 used whenever available.  Only the strategy surface this repo uses is
-implemented: integers / floats / sampled_from.
+implemented: integers / sampled_from / lists.
 """
 
 from __future__ import annotations
@@ -46,15 +46,9 @@ except ModuleNotFoundError:
     def integers(min_value: int, max_value: int) -> _Strategy:
         return _Strategy(lambda r: r.randint(min_value, max_value))
 
-    def floats(min_value: float, max_value: float) -> _Strategy:
-        return _Strategy(lambda r: r.uniform(min_value, max_value))
-
     def sampled_from(seq) -> _Strategy:
         items = list(seq)
         return _Strategy(lambda r: r.choice(items))
-
-    def booleans() -> _Strategy:
-        return _Strategy(lambda r: bool(r.getrandbits(1)))
 
     def lists(elements: _Strategy, min_size: int = 0, max_size: int = 10,
               unique: bool = False) -> _Strategy:
@@ -107,9 +101,7 @@ except ModuleNotFoundError:
 
     _strategies = types.ModuleType("hypothesis.strategies")
     _strategies.integers = integers
-    _strategies.floats = floats
     _strategies.sampled_from = sampled_from
-    _strategies.booleans = booleans
     _strategies.lists = lists
 
     _hyp = types.ModuleType("hypothesis")

@@ -25,16 +25,19 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import hashlib
 import math
+from typing import NamedTuple
 
+import jax
 import numpy as np
 from jax import lax
 
 from repro.kernels import dispatch
 from repro.kernels.ntt import ops as ntt_ops
 
-from . import encoder, ops, poly, trace
+from . import encoder, keyswitch, ops, poly, trace
 from .params import CkksParams
 
 
@@ -243,8 +246,7 @@ def _encoded_diags(ctx, plan: BsgsPlan, ds: list[int], rot: int, level: int,
     """Plaintexts of the diagonals ``ds`` pre-rotated by ``rot`` slots.
 
     Hits come from ``ctx.diag_cache``; the misses are encoded together, one upload
-    and one NTT, under a ``table.diag`` span.  Either way the op's
-    instruction stream reads each plaintext as a ``LOAD_PT``.
+    and one NTT, under a ``table.diag`` span.
     """
     params = ctx.params
     primes = params.q_primes[: level + 1]
@@ -257,14 +259,84 @@ def _encoded_diags(ctx, plan: BsgsPlan, ds: list[int], rot: int, level: int,
         for j, i in enumerate(miss):
             found[i] = lax.index_in_dim(data, j, keepdims=False)
             ctx.diag_cache.put(keys[i], found[i])
-    for _ in ds:
-        trace.record("LOAD_PT", params.n, level + 1)
     return [ops.Plaintext(data=f, level=level, scale=scale) for f in found]
 
 
 # ---------------------------------------------------------------------------
 # context implementations
 # ---------------------------------------------------------------------------
+
+
+class BsgsShape(NamedTuple):
+    """What a compiled BSGS application depends on besides its operands."""
+
+    n1: int
+    groups: tuple[tuple[int, tuple[int, ...]], ...]  # (giant g, its diagonals g·n1 + b), by g
+    babies: tuple[int, ...]  # baby rotations b that key-switch (b ≢ 0 mod slots)
+    giants: tuple[int, ...]  # giants g whose partial sum rotates by g·n1 (≢ 0 mod slots)
+    hoisted: bool  # the babies share one ModUp
+
+
+def _galois_element(params: CkksParams, r: int) -> int:
+    return pow(5, r % params.slots, 2 * params.n)
+
+
+def _bsgs_operands(ctx, ct: ops.Ciphertext, plan: BsgsPlan, scale: float):
+    """The host prelude of ``_apply_bsgs``: the application's static shape,
+    its encoded diagonals (in the order of ``shape.groups``, through
+    ``ctx.diag_cache``) and the pre-permuted Galois keys of its babies and
+    rotated giants (``keyswitch.hoisted_ksk``)."""
+    params = ctx.params
+    keys = ctx.require_keys()
+    hoisting = ctx.policy.hoisting
+    lv = ct.level
+    by_giant: dict[int, list[int]] = {}
+    for d in plan.diags:
+        by_giant.setdefault(d // plan.n1, []).append(d)
+    shape = BsgsShape(
+        n1=plan.n1,
+        groups=tuple((g, tuple(ds)) for g, ds in sorted(by_giant.items())),
+        babies=tuple(b for b in plan.baby_steps() if b % params.slots),
+        giants=tuple(g for g in sorted(by_giant) if (g * plan.n1) % params.slots),
+        hoisted=hoisting == "always" or (hoisting == "auto" and len(plan.baby_steps()) >= 2),
+    )
+    pts = [pt for g, ds in shape.groups
+           for pt in _encoded_diags(ctx, plan, ds, g * plan.n1, lv, scale)]  # pre-rotated diagonals
+    ksk = lambda r: keyswitch.hoisted_ksk(params, keys, _galois_element(params, r), lv)
+    baby_ksks = [ksk(b) for b in shape.babies]
+    giant_ksks = [ksk(g * plan.n1) for g in shape.giants]
+    return shape, pts, baby_ksks, giant_ksks
+
+
+def _bsgs_body(ctx, ct: ops.Ciphertext, shape: BsgsShape, pts: list[ops.Plaintext],
+               baby_ksks, giant_ksks) -> ops.Ciphertext:
+    """Σ_g rot_{g·n1}(Σ_b pt_{g·n1+b} ∘ rot_b(ct)), then one rescale, over the
+    operands ``_bsgs_operands`` resolved: the eager body of the op, which
+    ``_bsgs_program`` compiles."""
+    params = ctx.params
+    lv = ct.level
+    baby_ts = [_galois_element(params, b) for b in shape.babies]
+    if shape.hoisted:
+        rotated = ops._galois_group(ctx, ct, baby_ts, baby_ksks)
+    else:
+        rotated = [ops._galois_with(ctx, ct, t, k) for t, k in zip(baby_ts, baby_ksks)]
+    babies = dict(zip(shape.babies, rotated))  # b = 0 and b ≡ 0 mod slots: ct itself
+
+    pts, giant_ksks = iter(pts), dict(zip(shape.giants, giant_ksks))
+    total: ops.Ciphertext | None = None
+    for g, ds in shape.groups:
+        for _ in ds:
+            trace.record("LOAD_PT", params.n, lv + 1)
+        acc: ops.Ciphertext | None = None
+        for d in ds:
+            term = ops._mul_plain(ctx, babies.get(d % shape.n1, ct), next(pts), rescale_after=False)
+            acc = term if acc is None else ops._add(ctx, acc, term)
+        if g in giant_ksks:
+            t = _galois_element(params, g * shape.n1)
+            acc = ops._galois_with(ctx, acc, t, giant_ksks[g])
+        total = acc if total is None else ops._add(ctx, total, acc)
+
+    return ops._rescale(ctx, total)
 
 
 def _apply_bsgs(ctx, ct: ops.Ciphertext, plan: BsgsPlan,
@@ -278,38 +350,58 @@ def _apply_bsgs(ctx, ct: ops.Ciphertext, plan: BsgsPlan,
     separately.  All modes are bit-exact against each other.  Giant-step
     rotations apply to *different* ciphertexts (the per-group partial sums),
     so they cannot share a ModUp and always run the standard path.
+
+    One device dispatch: ``_bsgs_body`` compiled once per static shape
+    (``_bsgs_program``), bit-exact against running it eagerly; the diagonals
+    and keys are arguments of every call.  Inside an enclosing trace (a
+    caller's ``jax.jit``) the body runs inline instead.
     """
     params = ctx.params
-    keys = ctx.require_keys()
-    hoisting = ctx.policy.hoisting
     scale = params.scale if scale is None else scale
     lv = ct.level
+    shape, pts, baby_ksks, giant_ksks = _bsgs_operands(ctx, ct, plan, scale)
+    xs = (ct.c0, ct.c1, *(pt.data for pt in pts), *baby_ksks, *giant_ksks)
+    if any(isinstance(x, jax.core.Tracer) for x in xs):
+        return _bsgs_body(ctx, ct, shape, pts, baby_ksks, giant_ksks)
+    prog = _bsgs_program(params, ctx.policy, lv, shape, float(scale),
+                         tuple(k.shape for k in (*baby_ksks, *giant_ksks)),
+                         dispatch.default_device(), ops._rescale)
+    c0, c1 = prog(*xs)
+    return ops.Ciphertext(c0=c0, c1=c1, level=lv - 1,
+                          scale=ct.scale * scale / int(params.q_primes[lv]))
 
-    babies: dict[int, ops.Ciphertext] = {0: ct}
-    needed_b = plan.baby_steps()
-    if hoisting == "always" or (hoisting == "auto" and len(needed_b) >= 2):
-        babies.update(ops._rotate_hoisted_group(ctx, ct, needed_b, keys))
-    else:
-        for b in needed_b:
-            babies[b] = ops._rotate_standard(ctx, ct, b, keys)
 
-    by_giant: dict[int, list[int]] = {}
-    for d in plan.diags:
-        by_giant.setdefault(d // plan.n1, []).append(d)
+@functools.lru_cache(maxsize=64)
+@dispatch.spanned("table.bsgs_program")
+def _bsgs_program(params: CkksParams, policy, level: int, shape: BsgsShape, scale: float,
+                  key_shapes, device, rescale) -> ops._Program:
+    """``_bsgs_body`` compiled under a key-less context of ``params`` and
+    ``policy`` at ``level``: never keyed on the diagonals' values or on the
+    key set, so one program serves any weights and keys of this shape.
+    ``rescale`` is the ``ops._rescale`` the body traces, looked up per call:
+    a replaced one (a fault planted under the benchmark's check) builds a
+    program of its own instead of finding the one traced before."""
+    from .context import FheContext  # context imports this module
 
-    total: ops.Ciphertext | None = None
-    for g, ds in sorted(by_giant.items()):
-        acc: ops.Ciphertext | None = None
-        pts = _encoded_diags(ctx, plan, ds, g * plan.n1, lv, scale)  # pre-rotated diagonals
-        for d, pt in zip(ds, pts):
-            b = d % plan.n1
-            term = ops._mul_plain(ctx, babies[b], pt, rescale_after=False)
-            acc = term if acc is None else ops._add(ctx, acc, term)
-        if g:
-            acc = ops._rotate_standard(ctx, acc, g * plan.n1, keys)
-        total = acc if total is None else ops._add(ctx, total, acc)
+    ctx = FheContext(params=params, policy=policy)
+    n_pts = sum(len(ds) for _, ds in shape.groups)
+    n_babies = len(shape.babies)
 
-    return ops._rescale(ctx, total)
+    def body(c0, c1, *rest):
+        pts = [ops.Plaintext(x, level, scale) for x in rest[:n_pts]]
+        ksks = rest[n_pts:]
+        out = _bsgs_body(ctx, ops.Ciphertext(c0, c1, level, 1.0), shape, pts,
+                         ksks[:n_babies], ksks[n_babies:])
+        return out.c0, out.c1
+
+    limbs = (level + 1, params.n)
+    return ops._compile_body("ckks_bsgs", body, [limbs] * (2 + n_pts) + list(key_shapes))
+
+
+def bsgs_program_stats() -> dict:
+    """{"programs": cached, "calls": compiled applications run, "builds": programs traced}."""
+    info = _bsgs_program.cache_info()
+    return {"programs": info.currsize, "calls": info.hits + info.misses, "builds": info.misses}
 
 
 def _real_part(ctx, ct: ops.Ciphertext) -> ops.Ciphertext:

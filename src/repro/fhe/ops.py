@@ -254,34 +254,52 @@ def _mul(ctx, a: Ciphertext, b: Ciphertext, rlk: SwitchingKey,
         return _mul_eager(ctx, a, b, rlk, rescale_after)
     prog = _mul_program(ctx.params, ctx.policy, (a.level, b.level), rescale_after, rlk.k.shape,
                         dispatch.default_device())
-    dispatch.replay(prog.counts)
-    trace.replay(prog.instrs)
-    c0, c1 = prog.run(prog.consts, *xs)
+    c0, c1 = prog(*xs)
     level, scale = min(a.level, b.level), a.scale * b.scale
     if rescale_after:
         level, scale = level - 1, scale / int(ctx.params.q_primes[level])
     return Ciphertext(c0=c0, c1=c1, level=level, scale=scale)
 
 
-class _MulProgram(NamedTuple):
-    """A compiled ``_mul_eager`` and what its trace recorded."""
+class _Program(NamedTuple):
+    """An eager op body traced once into a compiled program, and what its trace recorded."""
 
-    run: Callable  # jitted (consts, a.c0, a.c1, b.c0, b.c1, rlk.k) -> (c0, c1)
+    run: Callable  # jitted (consts, *xs) -> the body's outputs
     consts: list  # the device tables the body reads: arguments, never embedded constants
     counts: dict  # kernel launches the body made ({op: n}), replayed on every call
     instrs: list  # its planner trace records, replayed likewise
+
+    def __call__(self, *xs):
+        dispatch.replay(self.counts)
+        trace.replay(self.instrs)
+        return self.run(self.consts, *xs)
+
+
+def _compile_body(name: str, body: Callable, shapes) -> _Program:
+    """Trace ``body`` once on uint32 arguments of ``shapes`` and jit it as
+    ``name`` (the module is ``jit_<name>``).  Every device array the body
+    closes over (limb constants, NTT, key-switch, rescale and permutation
+    tables) comes out of ``make_jaxpr`` as a const and is passed to the
+    program as an argument: captured, it would be embedded in the HLO."""
+    with dispatch.count_dispatches() as counts, trace.capture_trace() as instrs:
+        closed = jax.make_jaxpr(body)(*(jax.ShapeDtypeStruct(s, jnp.uint32) for s in shapes))
+
+    def run(consts, *xs):
+        return jax.core.eval_jaxpr(closed.jaxpr, consts, *xs)
+
+    run.__name__ = run.__qualname__ = name
+    # the oracle's NTT plans are numpy: upload them once, here
+    consts = [dispatch.upload(c) for c in closed.consts]
+    return _Program(jax.jit(run), consts, dict(counts), list(instrs))
 
 
 @functools.lru_cache(maxsize=256)
 @dispatch.spanned("table.mul_program")
 def _mul_program(params: CkksParams, policy, levels: tuple[int, int], rescale_after: bool,
-                 rlk_shape, device) -> _MulProgram:
-    """Trace ``_mul_eager`` once under a key-less context of ``params`` and
-    ``policy``, at these input levels.  Every device array the body closes
-    over (limb constants, NTT, key-switch and rescale tables) comes out of
-    ``make_jaxpr`` as a const and is passed to the program as an argument:
-    captured, it would be embedded in the HLO.  The key is an argument of
-    every call, so one program serves every key set of these params."""
+                 rlk_shape, device) -> _Program:
+    """``_mul_eager`` compiled under a key-less context of ``params`` and
+    ``policy``, at these input levels.  The key is an argument of every
+    call, so one program serves every key set of these params."""
     from .context import FheContext  # context imports this module
 
     ctx = FheContext(params=params, policy=policy)
@@ -292,15 +310,7 @@ def _mul_program(params: CkksParams, policy, levels: tuple[int, int], rescale_af
         return out.c0, out.c1
 
     shapes = [(lv + 1, params.n) for lv in (levels[0],) * 2 + (levels[1],) * 2] + [rlk_shape]
-    with dispatch.count_dispatches() as counts, trace.capture_trace() as instrs:
-        closed = jax.make_jaxpr(body)(*(jax.ShapeDtypeStruct(s, jnp.uint32) for s in shapes))
-
-    def ckks_mul(consts, *xs):
-        return jax.core.eval_jaxpr(closed.jaxpr, consts, *xs)
-
-    # the oracle's NTT plans are numpy: upload them once, here
-    consts = [dispatch.upload(c) for c in closed.consts]
-    return _MulProgram(jax.jit(ckks_mul), consts, dict(counts), list(instrs))
+    return _compile_body("ckks_mul", body, shapes)
 
 
 def mul_program_stats() -> dict:
@@ -449,28 +459,34 @@ def _rotate_hoisted_group(ctx, ct: Ciphertext, rots, keys: KeySet) -> dict[int, 
     by the input rotation values; each entry is bit-exact vs ``rotate``.
     """
     params = ctx.params
-    backend = ctx.backend
     uniq: dict[int, int] = {}  # r mod slots → galois element
     for r in rots:
         rm = r % params.slots
         if rm and rm not in uniq:
             uniq[rm] = pow(5, rm, 2 * params.n)
-    if not uniq:
-        return {r: ct for r in rots}
+    ksks = [keyswitch.hoisted_ksk(params, keys, t, ct.level) for t in uniq.values()]
+    by_rm = dict(zip(uniq, _galois_group(ctx, ct, list(uniq.values()), ksks)))
+    return {r: by_rm.get(r % params.slots, ct) for r in rots}
+
+
+def _galois_group(ctx, ct: Ciphertext, ts, ksks) -> list[Ciphertext]:
+    """σ_t(ct) for each Galois element of ``ts`` over one shared ModUp, given
+    each one's pre-permuted key (``keyswitch.hoisted_ksk``) in ``ksks``."""
+    if not ts:
+        return []
+    params = ctx.params
+    backend = ctx.backend
     lv = ct.level
     hd = keyswitch.hoisted_mod_up(ct.c1, params, lv, backend)
-    ksk_stack = jnp.stack(
-        [keyswitch.hoisted_ksk(params, keys, t, lv) for t in uniq.values()]
-    )
-    accs = keyswitch.hoisted_galois_ks(hd, ksk_stack, params, lv, backend)
+    accs = keyswitch.hoisted_galois_ks(hd, jnp.stack(ksks), params, lv, backend)
     ks = keyswitch.mod_down_group(accs, params, lv, backend)
-    by_rm: dict[int, Ciphertext] = {}
-    for i, (rm, t) in enumerate(uniq.items()):
+    out = []
+    for i, t in enumerate(ts):
         pair = lax.index_in_dim(ks, i, keepdims=False)  # static slices: no index transfer
         ks0, ks1 = (lax.index_in_dim(pair, c, keepdims=False) for c in (0, 1))
         c0, c1 = keyswitch.permute_last(ct.c0, ks0, ks1, t, params, lv, backend)
-        by_rm[rm] = Ciphertext(c0=c0, c1=c1, level=lv, scale=ct.scale)
-    return {r: (by_rm[r % params.slots] if r % params.slots else ct) for r in rots}
+        out.append(Ciphertext(c0=c0, c1=c1, level=lv, scale=ct.scale))
+    return out
 
 
 def _conjugate(ctx, ct: Ciphertext, keys: KeySet) -> Ciphertext:
@@ -488,10 +504,13 @@ def _apply_galois(ctx, ct: Ciphertext, t: int, keys: KeySet) -> Ciphertext:
     other — and the trace shape matches the classic permute-first pipeline
     (2×AUTO + key-switch + PADD).
     """
+    return _galois_with(ctx, ct, t, keyswitch.hoisted_ksk(ctx.params, keys, t, ct.level))
+
+
+def _galois_with(ctx, ct: Ciphertext, t: int, ksk_pre) -> Ciphertext:
+    """``_apply_galois`` given the pre-permuted key ``ksk_pre`` of σ_t."""
     params = ctx.params
     lv = ct.level
-    ksk_pre = keyswitch.hoisted_ksk(params, keys, t, lv)
     ks0, ks1 = keyswitch.key_switch_selected(ct.c1, params, lv, ksk_pre, ctx.backend)
     c0, c1 = keyswitch.permute_last(ct.c0, ks0, ks1, t, params, lv, ctx.backend)
     return Ciphertext(c0=c0, c1=c1, level=lv, scale=ct.scale)
-
